@@ -23,6 +23,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.kernels.membench.ops import make_buffer, membench
+from repro.launch.compile_cache import enable_compile_cache
 from repro.kernels.membench.ref import membench_ref
 from repro.kernels.paged_attention.kernel import fused_paged_decode
 from repro.kernels.paged_attention.ref import paged_decode_ref
@@ -157,6 +158,7 @@ if __name__ == "__main__":
                     help="machine-readable per-family records (the CI "
                          "kernel-gate artifact); '' skips the write")
     args = ap.parse_args()
+    enable_compile_cache()
     recs = records()
     for r in recs:
         print(_legacy_line(r))

@@ -335,6 +335,8 @@ if __name__ == "__main__":
     ap.add_argument("--out", default="BENCH_primitives.json")
     ap.add_argument("--alloc-out", default="BENCH_alloc.json")
     args = ap.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     if args.alloc_sweep:
         for r in alloc_sweep(args.alloc_out):
             print(r)

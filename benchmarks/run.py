@@ -28,6 +28,8 @@ def main() -> None:
                     choices=("membench", "primitives", "hostbench",
                              "kernels", "roofline"))
     args = ap.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     t_start = time.time()
     sections = []

@@ -739,6 +739,8 @@ def main(argv=None):
                     help="where the open-loop trace's report lands")
     args = ap.parse_args(argv)
 
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     from repro.configs import get_arch
     from repro.models import build_model
     from repro.serve.scheduler import plan_admission

@@ -201,22 +201,21 @@ class ImplChoice:
     algorithm: str       # e.g. "xf", "fa", "spin", "spin_backoff", "sleeping"
     strategy: WaitStrategy
     rationale: str
-    backend: str = "host"  # execution substrate: host | kernel | tpu | ref
+    backend: str = "host"  # execution substrate: host | kernel | ref
 
 
 def select_backend(machine: MachineAbstraction) -> str:
     """Pick the execution backend for a machine abstraction (DESIGN.md §8).
 
-    No-atomics accelerators run the Pallas kernels on hardware ("tpu");
-    measured hosts run the threading implementations ("host"); simulated
-    GPU abstractions plan through the interpret-mode kernels ("kernel").
+    Measured hosts run the threading implementations ("host"); every
+    other machine, a no-atomics TPU or a simulated GPU, plans through
+    the Pallas kernels ("kernel"), compiled on a TPU and interpreted
+    elsewhere.
     The registry in ``repro.sync.backends`` maps these names to
     implementations; plans on a live-only backend fall back to the
-    interpret kernel (see ``SyncLibrary.planning_backend_name``).
+    kernel backend (see ``SyncLibrary.planning_backend_name``).
     """
-    if not machine.has_atomics:
-        return "tpu"
-    if machine.name.startswith("host"):
+    if machine.has_atomics and machine.name.startswith("host"):
         return "host"
     return "kernel"
 

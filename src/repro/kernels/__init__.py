@@ -15,8 +15,27 @@
 # bucketed padding via repro.sync.window.WindowedPlanner) for schedulers
 # that replan varying-length traces every round. The preferred consumer
 # surface is repro.sync.SyncLibrary, which routes to these through the
-# backend registry ("kernel" = interpret, "tpu" = hardware, "ref" = the
-# oracles).
+# backend registry ("kernel" = compiled on a TPU and interpreted
+# elsewhere, "ref" = the oracles).
+
+from typing import Optional
+
+import jax
+
+
+def default_interpret() -> bool:
+    """The one place the kernel tier is chosen: Pallas kernels compile
+    on a TPU and run under the interpreter on every other platform."""
+    return jax.default_backend() != "tpu"
+
+
+def resolve_interpret(interpret: Optional[bool]) -> bool:
+    """``interpret`` if the caller pinned it, else ``default_interpret()``.
+    Every kernel wrapper calls this through the module attribute
+    (``kernels.resolve_interpret``), so the tier the sync backends report
+    and the tier the kernels run read one binding."""
+    return default_interpret() if interpret is None else interpret
+
 
 from repro.kernels.membench.ops import membench  # noqa: F401
 from repro.kernels.semaphore.ops import (  # noqa: F401

@@ -21,13 +21,15 @@ per step run in a ``fori_loop``, mirroring the paper's 1000-access loop.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams as _CompilerParams
+from repro import kernels
+
 
 LANE = 128  # f32 lane width; one (8, 128) tile = 4 KiB = one HBM tile
 
@@ -84,7 +86,7 @@ def membench_pallas(
     contentious: bool,
     write: bool,
     repeats: int = 16,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ):
     """Returns (final_buffer, per-step checksums)."""
     rows = buf.shape[0]
@@ -106,9 +108,9 @@ def membench_pallas(
             jax.ShapeDtypeStruct((rows, LANE), jnp.float32),
             jax.ShapeDtypeStruct((1, n_pad), jnp.float32),
         ),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
         ),
-        interpret=interpret,
+        interpret=kernels.resolve_interpret(interpret),
     )(buf.astype(jnp.float32))
     return out_buf, sums[0, :n_steps]

@@ -4,6 +4,7 @@ from __future__ import annotations
 
 import functools
 import time
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -15,7 +16,7 @@ from .ref import membench_ref
 @functools.partial(jax.jit, static_argnames=(
     "n_steps", "contentious", "write", "repeats", "interpret", "use_kernel"))
 def membench(buf, *, n_steps: int, contentious: bool, write: bool,
-             repeats: int = 16, interpret: bool = True,
+             repeats: int = 16, interpret: Optional[bool] = None,
              use_kernel: bool = True):
     """Run one cell of the adapted benchmark grid; returns (buffer, sums)."""
     if use_kernel:
@@ -34,7 +35,7 @@ def make_buffer(n_steps: int, key=None) -> jax.Array:
 
 
 def time_cell(n_steps: int = 64, *, contentious: bool, write: bool,
-              repeats: int = 64, interpret: bool = True) -> float:
+              repeats: int = 64, interpret: Optional[bool] = None) -> float:
     """Wall-time one benchmark cell (ms per 1000 accesses per step).
 
     On a real TPU (interpret=False) this fills the "TPU" row of the

@@ -10,15 +10,29 @@ block table ``(page_indices, last_page_len)`` per head and computes
 attention in a single pass, so each page of K/V crosses HBM exactly
 once.
 
-Walk order (DESIGN.md §16): grid ``(B, KV)`` — one program per
-(row, kv-head). Each program holds the row's ``G = H // KV`` query
-vectors for its kv head (the kv-major grouping ``expand_kv`` defines:
-query head ``h`` reads kv head ``h // G``, so ``q.reshape(B, KV, G,
-hd)`` lines the group up with one arena head slice) and walks the
-row's block-table entries in flat position order — page ``j`` covers
-positions ``[j*ps, (j+1)*ps)`` — accumulating online softmax
-``(m, l, acc)`` per query head. GQA is what makes the fusion pay: all
-``G`` queries of a group score against one page load.
+Walk order (DESIGN.md §16): grid ``(B,)`` — one program per row. It
+holds the row's ``H = KV * G`` query vectors (kv-major, the grouping
+``expand_kv`` defines: query head ``h`` reads kv head ``h // G``) and
+walks the row's live block-table entries in flat position order — page
+``j`` covers positions ``[j*ps, (j+1)*ps)`` — accumulating online
+softmax ``(m, l, acc)`` per query head. GQA is what makes the fusion
+pay: all ``G`` queries of a group score against one page load.
+
+Memory layout: the block table and the row lengths are scalar-prefetch
+operands (SMEM), so page ids are scalars the kernel indexes HBM with.
+The K/V arenas stay in HBM (``memory_space=pl.ANY``); each loop step
+DMAs one whole page ``[ps, KV, hd]`` into VMEM scratch and waits for
+it. A page is the smallest legal copy: one kv head's ``[ps, hd]``
+slice cuts the second-minor KV axis to 1, which the TPU tiling refuses,
+and viewing the arena as ``[pages, ps, KV * hd]`` makes XLA relayout
+the whole arena on every call. In VMEM the page is flattened to
+``[ps * KV, hd]`` and every query head scores against all of it, with
+the scores of mismatched heads masked; decode is bandwidth-bound, so
+the wasted MXU work is free while each page crosses HBM once per row.
+Only pages that hold positions the row attends are walked:
+``[lo, ceil(len / ps))`` with ``lo`` the first page of the sliding
+window. The copy is synchronous — double buffering and a head-major
+arena are ROADMAP S3.
 
 Sentinel handling: a table entry ``>= num_pages`` is unallocated (or
 masked for the round by the engine — paused rows under a starved CoW
@@ -35,12 +49,6 @@ regardless of arena dtype; masked lanes are excluded from ``p`` by a
 masked the running max stays at the ``NEG_INF`` sentinel and
 ``exp(NEG_INF - NEG_INF) == 1`` would leak weight). The final
 division guards ``l == 0`` so all-masked rows divide safely.
-
-Interpret tier (``interpret=True``) is the CI-gated correctness
-surface — the differential suite pins this kernel to the gather
-reference on CPU before any hardware run. On TPU hardware, tile
-alignment (hd and ps to the 128-lane layout) is the one expected
-change; the walk itself is already page-at-a-time sequential.
 """
 
 from __future__ import annotations
@@ -52,62 +60,87 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams as _CompilerParams
+from repro import kernels
 
 NEG_INF = -2.0e38  # matches models/attention.py's masking sentinel
 
 
 def paged_decode_kernel(
-    pages_ref,    # (1, P) i32: this row's block table
-    len_ref,      # (1, 1) i32: this row's cache length (positions to attend)
-    q_ref,        # (1, 1, G, hd): the kv-head group's query block
-    k_ref,        # (num_pages, ps, 1, hd): K arena, this kv head
-    v_ref,        # (num_pages, ps, 1, hd): V arena, this kv head
-    o_ref,        # out (1, 1, G, hd)
+    pages_ref,    # SMEM (B * P,) i32: flattened block tables
+    len_ref,      # SMEM (B,) i32: per-row cache lengths
+    q_ref,        # (1, H, hd): the row's queries, kv-major (H = KV * G)
+    k_hbm,        # HBM (num_pages, ps, KV, hd): K arena
+    v_hbm,        # HBM (num_pages, ps, KV, hd): V arena
+    o_ref,        # out (1, H, hd)
+    k_buf,        # VMEM scratch (ps, KV, hd): one page, every kv head
+    v_buf,        # VMEM scratch (ps, KV, hd)
+    sem,          # DMA semaphores (2,)
     *,
     num_pages: int,
     page_size: int,
+    table_len: int,
+    group: int,
     window: Optional[int],
     scale: float,
 ):
-    table_len = pages_ref.shape[1]
-    g, hd = q_ref.shape[2], q_ref.shape[3]
-    length = len_ref[0, 0]
-    q = q_ref[0, 0].astype(jnp.float32) * scale              # [G, hd]
+    row = pl.program_id(0)
+    n_heads, hd = q_ref.shape[1], q_ref.shape[2]
+    kv = n_heads // group
+    width = page_size * kv                  # flattened page: lane c is
+    length = len_ref[row]                   # position c // KV, head c % KV
+    q = q_ref[0].astype(jnp.float32) * scale                 # [H, hd]
+    # query head r reads kv head r // G; the flattened page's lane c
+    # holds kv head c % KV. Scores between mismatched heads are masked.
+    r = jax.lax.broadcasted_iota(jnp.int32, (n_heads, width), 0)
+    c = jax.lax.broadcasted_iota(jnp.int32, (n_heads, width), 1)
+    q_head = sum((r >= i * group).astype(jnp.int32) for i in range(1, kv))
+    head_ok = q_head == c % kv
+    offset = c // kv                                         # in-page position
 
     def body(j, carry):
         m, l, acc = carry
-        page = pages_ref[0, j]
+        page = pages_ref[row * table_len + j]
         live = page < num_pages                   # sentinel page -> all masked
         pid = jnp.clip(page, 0, num_pages - 1)
-        k = k_ref[pid, :, 0, :].astype(jnp.float32)          # [ps, hd]
-        v = v_ref[pid, :, 0, :].astype(jnp.float32)
-        pos = j * page_size + jax.lax.broadcasted_iota(
-            jnp.int32, (1, page_size), 1)                    # [1, ps]
-        ok = live & (pos < length)
+        k_copy = pltpu.make_async_copy(k_hbm.at[pid], k_buf, sem.at[0])
+        v_copy = pltpu.make_async_copy(v_hbm.at[pid], v_buf, sem.at[1])
+        k_copy.start()
+        v_copy.start()
+        k_copy.wait()
+        v_copy.wait()
+        k = k_buf[...].astype(jnp.float32).reshape(width, hd)
+        v = v_buf[...].astype(jnp.float32).reshape(width, hd)
+        pos = j * page_size + offset
+        ok = head_ok & live & (pos < length)
         if window is not None:
             ok = ok & (pos >= length - window)
-        s = jnp.dot(q, k.T, preferred_element_type=jnp.float32)  # [G, ps]
+        s = jax.lax.dot_general(
+            q, k, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)              # [H, ps * KV]
         s = jnp.where(ok, s, NEG_INF)
-        m_new = jnp.maximum(m, jnp.max(s, axis=-1))
+        m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
         # exclude masked lanes explicitly: with m_new still at NEG_INF,
         # exp(NEG_INF - NEG_INF) == 1 would weight a masked lane
-        p = jnp.where(ok, jnp.exp(s - m_new[:, None]), 0.0)
+        p = jnp.where(ok, jnp.exp(s - m_new), 0.0)
         alpha = jnp.exp(m - m_new)
-        l_new = l * alpha + jnp.sum(p, axis=-1)
-        acc_new = acc * alpha[:, None] + jnp.dot(
+        l_new = l * alpha + jnp.sum(p, axis=-1, keepdims=True)
+        acc_new = acc * alpha + jnp.dot(
             p, v, preferred_element_type=jnp.float32)
         return m_new, l_new, acc_new
 
-    m0 = jnp.full((g,), NEG_INF, jnp.float32)
-    l0 = jnp.zeros((g,), jnp.float32)
-    acc0 = jnp.zeros((g, hd), jnp.float32)
-    m, l, acc = jax.lax.fori_loop(0, table_len, body, (m0, l0, acc0))
+    hi = jnp.minimum((length + page_size - 1) // page_size, table_len)
+    lo = (0 if window is None
+          else jnp.maximum(length - window, 0) // page_size)
+    m0 = jnp.full((n_heads, 1), NEG_INF, jnp.float32)
+    l0 = jnp.zeros((n_heads, 1), jnp.float32)
+    acc0 = jnp.zeros((n_heads, hd), jnp.float32)
+    m, l, acc = jax.lax.fori_loop(lo, hi, body, (m0, l0, acc0))
     # all-masked rows (fully sentinel table / length 0) have l == 0 and
     # emit exact zeros — they contribute nothing downstream
-    out = acc / jnp.maximum(l, 1e-37)[:, None]
-    o_ref[0, 0] = out.astype(o_ref.dtype)
+    out = acc / jnp.maximum(l, 1e-37)
+    o_ref[0] = out.astype(o_ref.dtype)
 
 
 def fused_paged_decode(
@@ -118,7 +151,7 @@ def fused_paged_decode(
     cache_len: jax.Array,  # [B] i32 per-row lengths
     *,
     window: Optional[int] = None,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ) -> jax.Array:
     """One-pass block-table decode attention. Returns [B, KV, G, hd]."""
     b, kv, g, hd = q.shape
@@ -128,22 +161,28 @@ def fused_paged_decode(
 
     kernel = functools.partial(
         paged_decode_kernel, num_pages=num_pages, page_size=ps,
-        window=window, scale=scale)
-    return pl.pallas_call(
-        kernel,
-        grid=(b, kv),
-        in_specs=[
-            pl.BlockSpec((1, p_cap), lambda i, h: (i, 0)),
-            pl.BlockSpec((1, 1), lambda i, h: (i, 0)),
-            pl.BlockSpec((1, 1, g, hd), lambda i, h: (i, h, 0, 0)),
-            pl.BlockSpec((num_pages, ps, 1, hd), lambda i, h: (0, 0, h, 0)),
-            pl.BlockSpec((num_pages, ps, 1, hd), lambda i, h: (0, 0, h, 0)),
+        table_len=p_cap, group=g, window=window, scale=scale)
+    arena = pl.BlockSpec(memory_space=pl.ANY)
+    rows = pl.BlockSpec((1, kv * g, hd), lambda i, *_: (i, 0, 0))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(b,),
+        in_specs=[rows, arena, arena],
+        out_specs=rows,
+        scratch_shapes=[
+            pltpu.VMEM((ps, kv, hd), k_arena.dtype),
+            pltpu.VMEM((ps, kv, hd), v_arena.dtype),
+            pltpu.SemaphoreType.DMA((2,)),
         ],
-        out_specs=pl.BlockSpec((1, 1, g, hd), lambda i, h: (i, h, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((b, kv, g, hd), q.dtype),
-        compiler_params=_CompilerParams(
-            dimension_semantics=("parallel", "arbitrary"),
+    )
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((b, kv * g, hd), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",),
         ),
-        interpret=interpret,
-    )(pages.astype(jnp.int32), cache_len.astype(jnp.int32).reshape(b, 1),
-      q, k_arena, v_arena)
+        interpret=kernels.resolve_interpret(interpret),
+    )(pages.astype(jnp.int32).reshape(-1), cache_len.astype(jnp.int32),
+      q.reshape(b, kv * g, hd), k_arena, v_arena)
+    return out.reshape(b, kv, g, hd)

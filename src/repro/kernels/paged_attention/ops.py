@@ -8,9 +8,10 @@ the padded query heads are dropped before the kernel and re-padded
 with zeros after: the output projection masks their ``wo`` rows to
 zero, so zeros are exactly what the gather path computes for them too.
 
-``interpret`` defaults to "not on TPU": the CI/CPU tier runs the
-kernel under the Pallas interpreter (the differential suite pins it to
-the gather reference there); a TPU backend compiles it.
+``interpret=None`` leaves the tier to the platform
+(``repro.kernels.default_interpret``): the CI/CPU tier runs the kernel
+under the Pallas interpreter (the differential suite pins it to the
+gather reference there); a TPU backend compiles it.
 """
 
 from __future__ import annotations
@@ -21,10 +22,6 @@ import jax
 import jax.numpy as jnp
 
 from .kernel import fused_paged_decode
-
-
-def default_interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 def paged_decode_fused(
@@ -48,8 +45,6 @@ def paged_decode_fused(
         raise ValueError(f"num_heads {n_heads} not divisible by "
                          f"num_kv_heads {kv}")
     g = n_heads // kv
-    if interpret is None:
-        interpret = default_interpret()
     cl = jnp.asarray(cache_len, jnp.int32)
     if cl.ndim == 0:
         cl = jnp.broadcast_to(cl, (b,))

@@ -26,13 +26,15 @@ resolves the wait.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams as _CompilerParams
+from repro import kernels
+
 
 _BIG = 3.4e38  # python literal: traced into the kernel as an immediate
 
@@ -96,7 +98,7 @@ def sleeping_semaphore_pallas(
     hold: jax.Array,      # (N,) f32
     capacity: int,
     *,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ):
     """Returns (grant_times, release_times, waited)."""
     n = arrive_t.shape[0]
@@ -123,9 +125,9 @@ def sleeping_semaphore_pallas(
             pltpu.SMEM((2,), jnp.int32),
             pltpu.VMEM((1, k_pad), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
         ),
-        interpret=interpret,
+        interpret=kernels.resolve_interpret(interpret),
     )(a2, h2)
     return grant[0, :n], release[0, :n], waited[0, :n]

@@ -3,6 +3,7 @@
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import numpy as np
@@ -16,7 +17,8 @@ from .ref import sleeping_semaphore_ref
 @functools.partial(jax.jit,
                    static_argnames=("capacity", "interpret", "use_kernel"))
 def semaphore_admission(arrive_t, hold, *, capacity: int,
-                        interpret: bool = True, use_kernel: bool = True):
+                        interpret: Optional[bool] = None,
+                        use_kernel: bool = True):
     """Plan admission of N FIFO requests under a concurrency budget K.
 
     Returns (grant_times, release_times, waited) — the deterministic
@@ -46,7 +48,8 @@ _admission_window = WindowedPlanner(
 
 
 def semaphore_admission_window(arrive_t, hold, *, capacity: int,
-                               window: int = 32, interpret: bool = True,
+                               window: int = 32,
+                               interpret: Optional[bool] = None,
                                use_kernel: bool = True):
     """Fixed-shape admission planning for the serving hot loop.
 

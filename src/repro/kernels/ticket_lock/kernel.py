@@ -28,14 +28,14 @@ loop as the barrier's; on one core it exits on the first check.
 
 from __future__ import annotations
 
-import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams as _CompilerParams
+from repro import kernels
 
 
 def ticket_lock_kernel(
@@ -46,8 +46,6 @@ def ticket_lock_kernel(
     trace_ref,        # out (1, N) int32: observed turn at acquisition
     acc_ref,          # out (1, 1) f32: affine chain value
     state_ref,        # scratch SMEM (2,) int32: [ticket, turn]
-    *,
-    interpret: bool,
 ):
     i = pl.program_id(0)
     n_pad = grant_ref.shape[1]
@@ -61,26 +59,20 @@ def ticket_lock_kernel(
         trace_ref[...] = jnp.full_like(trace_ref, -1)
         acc_ref[0, 0] = 0.0
 
-    rid = arrival_ref[0, i]
+    # requester id of this grid step: an iota-masked lane reduction (a
+    # dynamic lane index into a vector row does not lower on the chip)
+    sel_i = iota == i
+    rid = jnp.sum(jnp.where(sel_i, arrival_ref[...], 0))
 
     # ---- lock(): one fetch-and-add to take a ticket ...
     my_ticket = state_ref[0]
     state_ref[0] = my_ticket + 1
 
-    # ... then sleep-wait until turn == ticket (bounded poll). Under
-    # interpret mode the turn word is read once before the loop: on a
-    # sequential core it cannot change while we poll, and jax<0.5
-    # interpret mode cannot discharge a ref read inside while_loop. On
-    # hardware the cond re-reads the turn word every iteration — the
-    # volatile poll that observes remote updates.
-    if interpret:
-        turn_now = state_ref[1]
-
-        def cond(polls):
-            return (turn_now != my_ticket) & (polls < 1_000_000)
-    else:
-        def cond(polls):
-            return (state_ref[1] != my_ticket) & (polls < 1_000_000)
+    # ... then sleep-wait until turn == ticket (bounded poll). The cond
+    # re-reads the turn word every iteration — the volatile poll that
+    # observes remote updates; on one sequential core it exits at once.
+    def cond(polls):
+        return (state_ref[1] != my_ticket) & (polls < 1_000_000)
 
     def body(polls):
         return polls + 1
@@ -92,7 +84,7 @@ def ticket_lock_kernel(
     mask_t = iota == my_ticket
     grant_ref[...] = jnp.where(mask_t, rid, grant_ref[...])
     trace_ref[...] = jnp.where(mask_t, observed_turn, trace_ref[...])
-    sel = (iota == i).astype(m_ref.dtype)
+    sel = sel_i.astype(m_ref.dtype)
     m_i = jnp.sum(m_ref[...] * sel)
     b_i = jnp.sum(b_ref[...] * sel)
     acc_ref[0, 0] = acc_ref[0, 0] * m_i + b_i
@@ -106,7 +98,7 @@ def ticket_lock_pallas(
     m: jax.Array,        # (N,) f32 per-requester multiplier
     b: jax.Array,        # (N,) f32 per-requester addend
     *,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ):
     """Returns (grant_order, turn_trace, acc)."""
     n = arrival.shape[0]
@@ -119,7 +111,7 @@ def ticket_lock_pallas(
 
     row_i = pl.BlockSpec((1, n_pad), lambda i: (0, 0))
     grant, trace, acc = pl.pallas_call(
-        functools.partial(ticket_lock_kernel, interpret=interpret),
+        ticket_lock_kernel,
         grid=(n,),
         in_specs=[row_i, row_i, row_i],
         out_specs=(row_i, row_i, pl.BlockSpec(memory_space=pltpu.SMEM)),
@@ -129,9 +121,9 @@ def ticket_lock_pallas(
             jax.ShapeDtypeStruct((1, 1), jnp.float32),
         ),
         scratch_shapes=[pltpu.SMEM((2,), jnp.int32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
         ),
-        interpret=interpret,
+        interpret=kernels.resolve_interpret(interpret),
     )(arrival2, m2, b2)
     return grant[0, :n], trace[0, :n], acc[0, 0]

@@ -3,6 +3,7 @@
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import numpy as np
@@ -14,7 +15,7 @@ from .ref import ticket_lock_ref
 
 
 @functools.partial(jax.jit, static_argnames=("interpret", "use_kernel"))
-def ticket_lock_run(arrival, m, b, *, interpret: bool = True,
+def ticket_lock_run(arrival, m, b, *, interpret: Optional[bool] = None,
                     use_kernel: bool = True):
     """Process N lock requests in ``arrival`` order under a FIFO ticket
     mutex; returns (grant_order, turn_trace, acc)."""
@@ -40,7 +41,8 @@ _ticket_window = WindowedPlanner(
 
 
 def ticket_lock_window(arrival, m=None, b=None, *, window: int = 32,
-                       interpret: bool = True, use_kernel: bool = True):
+                       interpret: Optional[bool] = None,
+                       use_kernel: bool = True):
     """Fixed-shape ticket-lock planning (power-of-2 bucketed windows —
     see ``repro.sync.window.WindowedPlanner``), so schedulers replanning
     varying request counts reuse one compiled kernel per bucket.
@@ -96,7 +98,7 @@ def ticket_lock_bounded_oracle(arrivals, holds, timeouts):
 
 
 def ticket_lock_batch_window(arrival, counts, *, window: int = 32,
-                             interpret: bool = True,
+                             interpret: Optional[bool] = None,
                              use_kernel: bool = True):
     """Plan one *batched-grant* allocator round under the FIFO ticket
     lock: requester ``i``'s single critical section grants

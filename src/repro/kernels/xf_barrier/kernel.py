@@ -30,13 +30,14 @@ form of "write your own word".
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams as _CompilerParams
+from repro import kernels
 
 
 def _row_iota(n: int) -> jax.Array:
@@ -58,7 +59,6 @@ def xf_barrier_kernel(
     straggler_ref,      # (1, N) int32: required slots that never arrived
     *,
     n_valid: int,
-    interpret: bool,
 ):
     i = pl.program_id(0)
     n = pl.num_programs(0)
@@ -82,45 +82,34 @@ def xf_barrier_kernel(
     def _master():
         checked = (iota < n_valid) & (required_ref[...] > 0)
 
-        # The "GPU sleeping" poll. Under interpret mode the flag block is
-        # read once before the loop: on a sequential core the present
-        # flags are already set before the master's grid step, and
-        # jax<0.5 interpret mode cannot discharge a ref read inside
-        # while_loop — the bounded loop only spends the poll budget on
-        # timeout, preserving the barrier-with-timeout shape. On
-        # hardware the body re-reads the flag block every iteration —
-        # the volatile re-read that observes remote DMA flag updates.
+        # The "GPU sleeping" poll: the body re-reads the flag block every
+        # iteration — the volatile re-read that observes remote DMA flag
+        # updates; the bounded budget makes it a barrier with timeout.
+        # The arrived flag is carried as int32: the chip's compiler
+        # cannot carry a bool through the loop.
         max_polls = max_polls_ref[0]
 
         def all_arrived():
-            return jnp.all(jnp.where(checked, arrive_ref[...] >= epoch,
-                                     True))
+            return jnp.min(jnp.where(checked & (arrive_ref[...] < epoch),
+                                     0, 1))
 
         def cond(state):
             polls, arrived = state
-            return jnp.logical_not(arrived) & (polls < max_polls)
+            return (arrived == 0) & (polls < max_polls)
 
-        if interpret:
-            arrived0 = all_arrived()
+        def body(state):
+            polls, _ = state
+            return polls + 1, all_arrived()
 
-            def body(state):
-                polls, _ = state
-                return polls + 1, arrived0
-        else:
-            arrived0 = all_arrived()
-
-            def body(state):
-                polls, _ = state
-                return polls + 1, all_arrived()
-
-        _, arrived = jax.lax.while_loop(cond, body, (jnp.int32(0), arrived0))
-        done_ref[0, 0] = arrived.astype(jnp.int32)
+        _, arrived = jax.lax.while_loop(
+            cond, body, (jnp.int32(0), all_arrived()))
+        done_ref[0, 0] = arrived
         straggler_ref[...] = jnp.where(
             checked & (arrive_ref[...] < epoch), 1, 0)
         # Broadcast release flags only on success (single masked store —
         # the master's "each thread sets unique positions" step).
         release_ref[...] = jnp.where(
-            checked & arrived, epoch, release_ref[...])
+            checked & (arrived > 0), epoch, release_ref[...])
 
 
 def xf_barrier_pallas(
@@ -130,7 +119,7 @@ def xf_barrier_pallas(
     required: jax.Array,   # (N,) who the master waits for
     *,
     max_polls: int = 1024,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ):
     """Run one barrier epoch. Returns (arrive', release, done, stragglers)."""
     n = arrive.shape[0]
@@ -140,8 +129,7 @@ def xf_barrier_pallas(
     def prep(x):
         return jnp.pad(x.astype(jnp.int32), (0, pad)).reshape(1, n_pad)
 
-    kernel = functools.partial(xf_barrier_kernel, n_valid=n,
-                               interpret=interpret)
+    kernel = functools.partial(xf_barrier_kernel, n_valid=n)
     out_shapes = (
         jax.ShapeDtypeStruct((1, n_pad), jnp.int32),  # arrive'
         jax.ShapeDtypeStruct((1, n_pad), jnp.int32),  # release
@@ -161,10 +149,10 @@ def xf_barrier_pallas(
         ],
         out_specs=(row, row, pl.BlockSpec(memory_space=pltpu.SMEM), row),
         out_shape=out_shapes,
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
         ),
-        interpret=interpret,
+        interpret=kernels.resolve_interpret(interpret),
     )(
         jnp.asarray([epoch], jnp.int32),
         jnp.asarray([max_polls], jnp.int32),

@@ -3,6 +3,7 @@
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -22,7 +23,7 @@ def xf_barrier(
     required: jax.Array,
     *,
     max_polls: int = 1024,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
     use_kernel: bool = True,
 ):
     """One XF barrier epoch over flag words.
@@ -69,7 +70,8 @@ _barrier_window = WindowedPlanner(
 
 def xf_barrier_window(arrive, epoch, present, required, *,
                       max_polls: int = 1024, window: int = 32,
-                      interpret: bool = True, use_kernel: bool = True):
+                      interpret: Optional[bool] = None,
+                      use_kernel: bool = True):
     """Fixed-shape barrier epoch (power-of-2 bucketed windows — see
     ``repro.sync.window.WindowedPlanner``), so membership churn across
     epochs reuses one compiled kernel per world-size bucket.

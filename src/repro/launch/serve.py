@@ -47,6 +47,7 @@ from __future__ import annotations
 
 import argparse
 import asyncio
+import dataclasses
 import time
 
 import jax
@@ -55,19 +56,22 @@ import numpy as np
 
 from repro.configs import get_arch
 from repro.core.abstraction import PrimitiveKind
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import build_model
 from repro.serve.engine import RequestState, ServeEngine, SlotServeEngine
 from repro.serve.faults import FaultPlan
 from repro.serve.frontend import AsyncFrontend, IntakeFullError
 from repro.serve.kv_pages import PageLeakError
 from repro.serve.scheduler import plan_admission
-from repro.sync import SyncLibrary
+from repro.sync import SyncLibrary, get_backend
 
 
 def build(args):
     cfg = get_arch(args.arch)
     if args.smoke:
         cfg = cfg.reduced()
+    if args.num_layers is not None:
+        cfg = dataclasses.replace(cfg, num_layers=args.num_layers)
     if cfg.is_encdec or cfg.frontend is not None:
         raise SystemExit("serve.py drives token-LM archs")
     model = build_model(cfg)
@@ -262,10 +266,28 @@ def run_legacy_loop(model, params, prompts, args):
     return tokens, dt, np.asarray(waits)
 
 
-def main(argv=None):
+def enforce_no_failures(engine) -> None:
+    """Without a fault plan nothing may fail: a FAILED request then
+    means a real error was absorbed, and the run exits non-zero."""
+    if engine.fault_plan is not None:
+        return
+    failed = [r.rid for r in engine.finished
+              if r.state is RequestState.FAILED]
+    if failed:
+        print(f"[serve] FATAL: {len(failed)} requests FAILED without a "
+              f"fault plan (rids {failed[:8]})")
+        raise SystemExit(1)
+
+
+def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--smoke", action="store_true")
+    ap.add_argument("--num-layers", type=int, default=None,
+                    help="depth cut: serve only the first N layers at "
+                         "the config's published widths (random "
+                         "weights), for a model whose full depth does "
+                         "not fit the device")
     ap.add_argument("--requests", type=int, default=32)
     ap.add_argument("--capacity", type=int, default=8)
     ap.add_argument("--prompt-len", type=int, default=32)
@@ -381,14 +403,19 @@ def main(argv=None):
     ap.add_argument("--legacy", action="store_true",
                     help="also run the old per-request loop")
     ap.add_argument("--sync-backend", default="auto",
-                    choices=("auto", "host", "kernel", "tpu", "ref"),
+                    choices=("auto", "host", "kernel", "ref"),
                     help="admission-planner backend (auto = pick from "
                          "the machine abstraction)")
     ap.add_argument("--admission-sem", default="auto",
                     choices=("auto", "sleeping", "spin", "spin_backoff"),
                     help="live admission-gate semaphore algorithm "
                          "(auto = paper Table-5 selection)")
-    args = ap.parse_args(argv)
+    return ap
+
+
+def main(argv=None):
+    args = build_parser().parse_args(argv)
+    enable_compile_cache()
 
     cfg, model, params = build(args)
     sync = make_sync_library(args)
@@ -396,9 +423,10 @@ def main(argv=None):
                          semaphore_initial=args.capacity)
     gate = (choice.algorithm if args.admission_sem == "auto"
             else args.admission_sem)
+    planner = get_backend(sync.planning_backend_name())
     print(f"[serve] sync: gate={gate} (selected "
           f"{choice.algorithm}/{choice.strategy.value}) "
-          f"planner={sync.planning_backend_name()} "
+          f"planner={planner.name}[{planner.tier}] "
           f"machine={sync.machine.name}({sync.machine_class()})")
     key = jax.random.PRNGKey(args.seed)
     prompts = np.asarray(jax.random.randint(
@@ -516,6 +544,7 @@ def main(argv=None):
                   f"{engine.pool.pages.num_pages})")
 
     enforce_leak_gate(engine)
+    enforce_no_failures(engine)
 
     if args.legacy:
         tokens, dt_old, waits = run_legacy_loop(model, params, prompts, args)

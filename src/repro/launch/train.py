@@ -25,6 +25,7 @@ import numpy as np
 
 from repro.configs import get_arch
 from repro.core.coordinator import ClusterCoordinator
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import build_model
 from repro.train import optimizer as opt
 from repro.train.checkpoint import CheckpointManager
@@ -48,6 +49,7 @@ def main(argv=None):
     ap.add_argument("--log-every", type=int, default=10)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = get_arch(args.arch)
     if args.smoke:

@@ -28,7 +28,7 @@ single-stream generation). ``SlotServeEngine`` is the production path:
     ``grant_log`` so callers can verify it.
 
 All primitive access goes through the injected ``SyncLibrary`` (the
-``sync`` constructor argument): the planner backend (interpret kernel /
+``sync`` constructor argument): the planner backend (Pallas kernel /
 hardware / pure-jnp ref) and the live gate's algorithm are configuration
 — ``launch/serve.py`` exposes both as CLI flags.
 
@@ -61,19 +61,6 @@ from repro.sync import SyncLibrary
 
 PyTree = Any
 
-
-class RoundDispatchError(RuntimeError):
-    """A scheduler round's jitted dispatch failed (DESIGN.md §15).
-
-    Carries the blamed request id when the underlying fault named one;
-    the engine's recovery loop rolls the round back, retries with
-    backoff, and quarantines the blamed request after
-    ``quarantine_after`` consecutive failures.
-    """
-
-    def __init__(self, cause: BaseException, rid: Optional[int] = None):
-        self.rid = rid
-        super().__init__(f"round dispatch failed: {cause!r}")
 
 #: Write-drop sentinel for chunked prefill: pad lanes of a partial last
 #: chunk (and rows not advancing this round) carry this as their cache
@@ -1756,19 +1743,21 @@ class SlotServeEngine:
         if not self.active:
             self._flush_deferred_frees()
             return 0
-        # round-level recovery (DESIGN.md §15): a failed dispatch rolls
+        # round-level recovery (DESIGN.md §15): an injected fault rolls
         # the round back (the PRNG key is the only host state the
         # dispatch section had consumed) and retries with linear
-        # backoff; repeated failures blamed on one request quarantine
+        # backoff; repeated faults blamed on one request quarantine
         # exactly that request. The attempt cap bounds even an
         # always-faulting run: every failure advances some rid's
         # streak, so quarantines drain the active set before it trips.
+        # Any other error — a compile error, device memory exhausted —
+        # is not transient: it propagates to the caller untouched.
         attempts = 0
         max_attempts = self.quarantine_after * (len(self.active) + 1)
         while True:
             try:
                 n = self._run_round()
-            except (InjectedFault, RoundDispatchError) as exc:
+            except InjectedFault as exc:
                 attempts += 1
                 self.rounds_retried += 1
                 self._recover_round(exc)
@@ -1785,12 +1774,11 @@ class SlotServeEngine:
 
     def _run_round(self) -> int:
         """The round body ``step``'s recovery loop drives: plan, grow,
-        dispatch, harvest, retire. Raises ``InjectedFault`` /
-        ``RoundDispatchError`` only from the dispatch section, which
-        restores the PRNG key before re-raising — everything the
-        section had not yet touched (lengths, cursors, block tables)
-        is still the pre-round state, so a retry replays the round
-        exactly."""
+        dispatch, harvest, retire. Raises ``InjectedFault`` only from
+        the dispatch section, which restores the PRNG key before
+        re-raising — everything the section had not yet touched
+        (lengths, cursors, block tables) is still the pre-round state,
+        so a retry replays the round exactly."""
         steps = self.decode_chunk
         chunked = self.prefill_chunk > 0
         planned: List[int] = []
@@ -1904,9 +1892,6 @@ class SlotServeEngine:
         except InjectedFault:
             self._key = key0
             raise
-        except Exception as exc:
-            self._key = key0
-            raise RoundDispatchError(exc) from exc
         self.decode_dispatches += 1
         self.pool.adopt(cache)
         self._last_tok = np.array(tok)     # writable copy (inserts mutate)

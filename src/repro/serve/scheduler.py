@@ -15,7 +15,7 @@ semaphore, and the paper's two findings drive the design:
 Every primitive is reached through an injected ``repro.sync.SyncLibrary``
 — no direct imports of hostsync or the kernel ops — so the live gate's
 algorithm (sleeping vs spin, the spin-vs-sleep admission knob) and the
-planner's backend (interpret kernel / hardware / pure-jnp ref) are
+planner's backend (Pallas kernel / hardware / pure-jnp ref) are
 configuration, not code. ``AdmissionController`` is the host-side gate;
 ``plan_admission`` is the planner used for batching decisions and
 reported in benchmarks/serving.

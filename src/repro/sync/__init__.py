@@ -4,8 +4,8 @@
 #   protocols.py — uniform Barrier/Mutex/Semaphore protocols + the
 #                  deterministic *Plan timeline types every backend returns
 #   backends.py  — registry of implementation substrates: host (threading,
-#                  observed-execution plans), kernel (Pallas interpret),
-#                  tpu (Pallas on hardware), ref (pure-jnp oracles)
+#                  observed-execution plans), kernel (Pallas, compiled on TPU,
+#                  interpreted elsewhere), ref (pure-jnp oracles)
 #   library.py   — SyncLibrary: machine abstraction -> (backend, algorithm,
 #                  wait-strategy) triple, live constructors + plan() forms,
 #                  cached host classification

@@ -7,10 +7,9 @@ A *backend* is where a primitive actually runs:
                 the live primitives under a driver-owned event clock and
                 *observing* the grant order (this is what the
                 cross-backend equivalence tests pin the kernels against).
-  ``kernel``  — the Pallas kernels under ``interpret=True`` (runs
-                anywhere; the CI tier).
-  ``tpu``     — the same Pallas kernels with ``interpret=False``
-                (real-hardware tier; requires a TPU runtime).
+  ``kernel``  — the Pallas kernels, compiled on a TPU and run under the
+                interpreter elsewhere (``repro.kernels.default_interpret``
+                decides; the CPU/CI tier is the interpreter).
   ``ref``     — the pure-jnp oracles (``kernels/*/ref.py``).
 
 Live objects always execute on the host control plane — a Pallas kernel
@@ -65,6 +64,8 @@ class SyncBackend:
     name = "base"
     #: cheap, deterministic plans suitable for a scheduler hot loop
     fast_plans = False
+    #: where this backend's plans execute
+    tier = "host"
 
     # ------------------------------------------------------------- live form
     def mutex(self, algorithm: str, strategy: WaitStrategy):
@@ -101,35 +102,44 @@ class SyncBackend:
 
 
 # ---------------------------------------------------------------------------
-# Pallas-kernel backends (interpret / hardware) and the pure-jnp reference.
+# The Pallas-kernel backend and the pure-jnp reference.
 # Kernel modules are imported lazily inside the methods so that importing
 # ``repro.sync`` never pulls in jax.pallas (and so the kernel ops modules
 # can themselves import ``repro.sync.window`` without a cycle).
 # ---------------------------------------------------------------------------
 
 class PallasBackend(SyncBackend):
-    """Plans via the Pallas kernels (``interpret`` picks the tier)."""
+    """Plans via the Pallas kernels, whose tier the platform decides;
+    ``use_kernel=False`` plans with the pure-jnp oracles."""
 
     fast_plans = True
 
-    def __init__(self, name: str, interpret: bool, use_kernel: bool = True):
+    def __init__(self, name: str, use_kernel: bool = True):
         self.name = name
-        self.interpret = interpret
         self.use_kernel = use_kernel
+
+    @property
+    def tier(self) -> str:
+        """Where plans run: ``compiled`` kernel, ``interpret`` kernel,
+        or the ``ref`` oracles."""
+        if not self.use_kernel:
+            return "ref"
+        from repro import kernels
+        return "interpret" if kernels.resolve_interpret(None) else "compiled"
 
     def plan_semaphore(self, arrivals, holds, capacity, *, window=None):
         from repro.kernels.semaphore.ops import semaphore_admission_window
         g, r, w = semaphore_admission_window(
             arrivals, holds, capacity=capacity,
             window=window if window else 32,
-            interpret=self.interpret, use_kernel=self.use_kernel)
+            use_kernel=self.use_kernel)
         return np.asarray(g), np.asarray(r), np.asarray(w), None
 
     def plan_mutex(self, arrival, m, b, *, window=None):
         from repro.kernels.ticket_lock.ops import ticket_lock_window
         g, t, acc = ticket_lock_window(
             arrival, m, b, window=window if window else 32,
-            interpret=self.interpret, use_kernel=self.use_kernel)
+            use_kernel=self.use_kernel)
         return np.asarray(g), np.asarray(t), float(acc)
 
     def plan_barrier(self, arrive, epoch, present, required, *,
@@ -138,7 +148,7 @@ class PallasBackend(SyncBackend):
         a, rel, done, strag = xf_barrier_window(
             arrive, epoch, present, required, max_polls=max_polls,
             window=window if window else 32,
-            interpret=self.interpret, use_kernel=self.use_kernel)
+            use_kernel=self.use_kernel)
         return (np.asarray(a), np.asarray(rel), int(done),
                 np.asarray(strag))
 
@@ -389,7 +399,5 @@ def available_backends() -> Tuple[str, ...]:
 
 
 register_backend("host", HostBackend())
-register_backend("kernel", PallasBackend("kernel", interpret=True))
-register_backend("tpu", PallasBackend("tpu", interpret=False))
-register_backend("ref", PallasBackend("ref", interpret=True,
-                                      use_kernel=False))
+register_backend("kernel", PallasBackend("kernel"))
+register_backend("ref", PallasBackend("ref", use_kernel=False))

@@ -157,7 +157,8 @@ class SyncLibrary:
 
     def planning_backend_name(self) -> str:
         """Backend for hot-loop planning: the pinned/selected backend if
-        it plans cheaply, else the interpret kernel (runs everywhere)."""
+        it plans cheaply, else the kernel backend (compiled on a TPU,
+        interpreted elsewhere)."""
         name = self.backend_name()
         return name if get_backend(name).fast_plans else "kernel"
 

@@ -2,7 +2,7 @@
 
 The container image does not ship hypothesis; the property tests only use
 ``@given`` over ``st.integers`` / ``st.sampled_from`` with
-``@settings(max_examples=N, deadline=None)``.  This shim reproduces that
+``@settings(max_examples=N, deadline=None)`` and ``@example``.  This shim reproduces that
 subset with a seeded PRNG so the tests stay deterministic per run order
 and still sweep a spread of examples.  When the real hypothesis is
 installed it is used verbatim.
@@ -24,7 +24,7 @@ import random
 from typing import Any, Callable, Sequence
 
 try:  # pragma: no cover - exercised only when hypothesis is installed
-    from hypothesis import given, settings
+    from hypothesis import example, given, settings
     from hypothesis import strategies as st
     HAVE_HYPOTHESIS = True
 except ImportError:
@@ -66,10 +66,19 @@ except ImportError:
             return fn
         return deco
 
+    def example(**drawn: Any):
+        def deco(fn):
+            fn._compat_examples = [drawn] + getattr(fn, "_compat_examples",
+                                                    [])
+            return fn
+        return deco
+
     def given(**strategies: _Strategy):
         def deco(fn):
             @functools.wraps(fn)
             def wrapper(*args, **kwargs):
+                for drawn in getattr(fn, "_compat_examples", []):
+                    fn(*args, **drawn, **kwargs)
                 n = getattr(fn, "_compat_max_examples", 20)
                 # Seed on the test name so each test gets a stable but
                 # distinct example stream across runs.
@@ -101,4 +110,5 @@ except ImportError:
 
 strategies = st
 
-__all__ = ["given", "settings", "st", "strategies", "HAVE_HYPOTHESIS"]
+__all__ = ["example", "given", "settings", "st", "strategies",
+           "HAVE_HYPOTHESIS"]

@@ -316,6 +316,32 @@ def test_poisoned_request_is_quarantined_alone(lm_setup):
     assert survivors == len(base) - 1       # everyone but the poisoned rid
 
 
+def test_device_errors_propagate_without_retry(lm_setup):
+    """Only injected faults are transient. A compile error or exhausted
+    device memory inside a round propagates out of ``step`` on the
+    first attempt: no retry, no quarantine, nothing reported FAILED."""
+    cfg, model, params = lm_setup
+    eng = SlotServeEngine(model, params, capacity=2, max_len=64,
+                          kv_layout="paged", page_size=4, seed=0,
+                          decode_chunk=2)
+    calls = []
+
+    def exhausted(*args, **kwargs):
+        calls.append(1)
+        raise jax.errors.JaxRuntimeError(
+            "RESOURCE_EXHAUSTED: out of memory allocating the round")
+
+    for name in ("_chunk", "_round", "_bucket_chunk", "_bucket_round"):
+        setattr(eng, name, exhausted)
+    eng.submit(np.arange(1, 7), max_new_tokens=4)
+    with pytest.raises(jax.errors.JaxRuntimeError, match="RESOURCE_EXH"):
+        eng.step()
+    assert calls == [1]
+    assert eng.rounds_retried == 0
+    assert eng.requests_quarantined == 0
+    assert not [r for r in eng.finished if r.state is RequestState.FAILED]
+
+
 def test_engine_watchdog_counts_stuck_holders(lm_setup):
     """`allocator_watchdog_s` arms the pool mutex; a stuck-holder fault
     plan must surface `watchdog_trips` in engine stats."""
@@ -424,3 +450,22 @@ def test_launch_leak_gate_fails_loudly_on_leak(lm_setup, capsys):
         enforce_leak_gate(eng)
     assert ei.value.code == 1
     assert "FATAL" in capsys.readouterr().out
+
+
+def test_launch_exits_nonzero_on_failure_without_fault_plan(lm_setup,
+                                                            capsys):
+    """Without a fault plan a FAILED request means a real error was
+    absorbed: the launch driver exits non-zero. Under a fault plan
+    quarantine is the designed outcome and the run goes on."""
+    from repro.launch.serve import enforce_no_failures
+
+    cfg, model, params = lm_setup
+    _, _, eng = _drive(model, params, None, vocab=cfg.vocab_size)
+    enforce_no_failures(eng)                     # all FINISHED: no exit
+    eng.finished[0].state = RequestState.FAILED
+    with pytest.raises(SystemExit) as ei:
+        enforce_no_failures(eng)
+    assert ei.value.code == 1
+    assert "FATAL" in capsys.readouterr().out
+    eng.fault_plan = FaultPlan(0)
+    enforce_no_failures(eng)

@@ -32,12 +32,18 @@ def lib():
 
 
 # ----------------------------------------------------------------- registry
-def test_builtin_backends_registered():
-    assert set(available_backends()) >= {"host", "kernel", "tpu", "ref"}
+def test_builtin_backends_registered(monkeypatch):
+    assert set(available_backends()) >= {"host", "kernel", "ref"}
     assert get_backend("kernel").fast_plans
     assert not get_backend("host").fast_plans
     with pytest.raises(KeyError):
         get_backend("no-such-backend")
+    # the kernel backend's tier follows the platform
+    assert [get_backend(n).tier for n in ("host", "kernel", "ref")] \
+        == ["host", "interpret", "ref"]
+    import repro.kernels
+    monkeypatch.setattr(repro.kernels, "default_interpret", lambda: False)
+    assert get_backend("kernel").tier == "compiled"
 
 
 def test_register_custom_backend(lib):
@@ -65,12 +71,12 @@ def test_register_custom_backend(lib):
 
 # ---------------------------------------------------------- selection triple
 def test_selection_triple_backend_axis():
-    assert select_backend(TPU_V5E) == "tpu"
+    assert select_backend(TPU_V5E) == "kernel"
     assert select_backend(TESLA) == "kernel"
     assert select_backend(sync_library.HOST_NOMINAL) == "host"
     # select_impl carries the backend in the triple, overridable
     c = select_impl(TPU_V5E, PrimitiveKind.SEMAPHORE)
-    assert (c.backend, c.algorithm) == ("tpu", "sleeping")
+    assert (c.backend, c.algorithm) == ("kernel", "sleeping")
     c = select_impl(FERMI, PrimitiveKind.MUTEX, backend="ref")
     assert (c.backend, c.algorithm) == ("ref", "spin_backoff")
 
@@ -81,7 +87,7 @@ def test_library_pins_override_selection(lib):
     assert isinstance(lib.semaphore(2), SleepingSemaphore)
     assert isinstance(lib.barrier(3), XFBarrier)
     tpu_lib = SyncLibrary(machine=TPU_V5E)
-    assert tpu_lib.backend_name() == "tpu"
+    assert tpu_lib.backend_name() == "kernel"
     # live-only fallback: plans on a pinned "host" library use the kernel
     assert SyncLibrary.host_default(backend="host") \
         .planning_backend_name() == "kernel"
@@ -251,10 +257,12 @@ def test_for_host_probe_cached_with_refresh_escape(monkeypatch):
 @settings(max_examples=5, deadline=None)
 @given(n=st.integers(4, 12), cap=st.integers(1, 4),
        seed=st.integers(0, 10_000))
-def test_semaphore_plans_equivalent_across_backends(lib, n, cap, seed):
+def test_semaphore_plans_equivalent_across_backends(n, cap, seed):
     """Property: the real Algorithm-5 host semaphore (threads, observed),
     the Pallas kernel, and the jnp oracle produce the same grant order,
     waited set, and release timeline on a random trace."""
+    # built per example: Hypothesis rejects function-scoped fixtures
+    lib = SyncLibrary.host_default()
     rng = np.random.default_rng(seed)
     arrivals = np.sort(rng.uniform(0, 3, n)).astype(np.float32)
     holds = rng.uniform(1, 3, n).astype(np.float32)
@@ -277,10 +285,12 @@ def test_semaphore_plans_equivalent_across_backends(lib, n, cap, seed):
 
 @settings(max_examples=5, deadline=None)
 @given(n=st.integers(2, 10), seed=st.integers(0, 10_000))
-def test_mutex_plans_equivalent_across_backends(lib, n, seed):
+def test_mutex_plans_equivalent_across_backends(n, seed):
     """Property: real TicketMutex threads under contention grant in the
     same FIFO order — and serialize the same order-sensitive affine
     chain — as the kernel and the oracle."""
+    # built per example: Hypothesis rejects function-scoped fixtures
+    lib = SyncLibrary.host_default()
     rng = np.random.default_rng(seed)
     arrival = rng.permutation(n).astype(np.int32)
     m = rng.uniform(0.5, 1.5, n).astype(np.float32)
@@ -300,10 +310,12 @@ def test_mutex_plans_equivalent_across_backends(lib, n, seed):
 
 @settings(max_examples=5, deadline=None)
 @given(n=st.integers(2, 12), seed=st.integers(0, 10_000))
-def test_barrier_plans_equivalent_across_backends(lib, n, seed):
+def test_barrier_plans_equivalent_across_backends(n, seed):
     """Property: one XF-barrier epoch completes/stalls identically —
     done bit, straggler bitmap, release flags on required slots — on
     real threads, the kernel, and the oracle."""
+    # built per example: Hypothesis rejects function-scoped fixtures
+    lib = SyncLibrary.host_default()
     rng = np.random.default_rng(seed)
     present = (rng.uniform(size=n) < 0.8).astype(np.int64)
     required = (rng.uniform(size=n) < 0.8).astype(np.int64)
@@ -387,11 +399,13 @@ def test_live_semaphore_timeout_rolls_count_back(lib):
 
 @settings(max_examples=5, deadline=None)
 @given(n=st.integers(3, 10), seed=st.integers(0, 10_000))
-def test_bounded_mutex_plans_match_oracle_across_backends(lib, n, seed):
+def test_bounded_mutex_plans_match_oracle_across_backends(n, seed):
     """Property: the bounded-wait mutex timeline — who acquired, who
     burned its ticket, the shared turn clock — agrees with the
     step-exact numpy oracle on host (observed execution), kernel, and
     ref alike."""
+    # built per example: Hypothesis rejects function-scoped fixtures
+    lib = SyncLibrary.host_default()
     from repro.kernels.ticket_lock.ops import ticket_lock_bounded_oracle
 
     rng = np.random.default_rng(seed)
