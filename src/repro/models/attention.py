@@ -329,6 +329,12 @@ def banded_attention(
 # positions [j*ps, (j+1)*ps) — so the gathered view feeds the same
 # ``decode_attention`` masking as the contiguous layout.
 
+#: Name scope around the paged read path (gather or fused, decode or
+#: chunk): op metadata only, so a profiler trace can attribute device
+#: time to the attention read whatever its ops are called.
+PAGED_ATTENTION_SCOPE = "paged_attention"
+
+
 def gather_pages(arena: jax.Array, pages: jax.Array) -> jax.Array:
     """[num_pages, ps, ...] arena + [B, P] block table -> [B, P*ps, ...]
     per-row contiguous view. Sentinel/unallocated entries clip to the
@@ -509,17 +515,20 @@ def paged_decode_attention(p, cfg, q, k_arena, v_arena, pages, cache_len, *,
     slots). The interpret-tier differential suite pins it to the
     gather path; serving selects it via
     ``SlotServeEngine(attention_impl="fused")``.
+
+    Both run under the ``PAGED_ATTENTION_SCOPE`` name scope.
     """
-    if impl == "fused":
-        return paged_decode_fused(q, k_arena, v_arena, pages, cache_len,
-                                  cfg.num_heads, window=window)
-    if impl != "gather":
+    if impl not in ("gather", "fused"):
         raise ValueError(f"unknown paged decode impl {impl!r}; "
                          f"expected 'gather' or 'fused'")
-    kb = gather_pages(k_arena, pages)
-    vb = gather_pages(v_arena, pages)
-    return cached_decode_attention(p, cfg, q, kb, vb, cache_len,
-                                   window=window)
+    with jax.named_scope(PAGED_ATTENTION_SCOPE):
+        if impl == "fused":
+            return paged_decode_fused(q, k_arena, v_arena, pages, cache_len,
+                                      cfg.num_heads, window=window)
+        kb = gather_pages(k_arena, pages)
+        vb = gather_pages(v_arena, pages)
+        return cached_decode_attention(p, cfg, q, kb, vb, cache_len,
+                                       window=window)
 
 
 def cached_chunk_attention(p, cfg, q, k_cache, v_cache, q_positions, *,
@@ -535,11 +544,13 @@ def paged_chunk_attention(p, cfg, q, k_arena, v_arena, pages, q_positions, *,
                           window: Optional[int]) -> jax.Array:
     """Block-table chunked prefill: gather the row's pages into position
     order, then attend at each query's absolute position (same gathered
-    view and masking family as ``paged_decode_attention``)."""
-    kb = gather_pages(k_arena, pages)
-    vb = gather_pages(v_arena, pages)
-    return cached_chunk_attention(p, cfg, q, kb, vb, q_positions,
-                                  window=window)
+    view and masking family as ``paged_decode_attention``, and the same
+    name scope)."""
+    with jax.named_scope(PAGED_ATTENTION_SCOPE):
+        kb = gather_pages(k_arena, pages)
+        vb = gather_pages(v_arena, pages)
+        return cached_chunk_attention(p, cfg, q, kb, vb, q_positions,
+                                      window=window)
 
 
 def naive_reference_attention(q, k, v, *, causal, window=None):

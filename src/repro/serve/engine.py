@@ -49,6 +49,7 @@ from typing import Any, Deque, Dict, List, Optional, Set, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import StepTraceAnnotation, TraceAnnotation
 
 from repro.serve.dispatch import DecodeDispatchCache
 from repro.serve.faults import FaultPlan, InjectedFault
@@ -69,6 +70,15 @@ PyTree = Any
 #: (``mode="drop"``); a negative position would be *wrapped* into a
 #: valid cell by JAX's index semantics, silently corrupting live KV.
 _DROP_POS = 2 ** 30
+
+#: Host spans the serving program records on the profiler's trace
+#: (``jax.profiler``; DESIGN.md §17), one per stretch of host work in a
+#: scheduler round: ``serve.*`` inside ``SlotServeEngine.step`` (the
+#: round itself is ``serve.step``, a step annotation numbered by the
+#: engine's round counter), ``frontend.*`` in ``AsyncFrontend``'s loop.
+SPANS = ("serve.step", "serve.admit", "serve.plan", "serve.grant",
+         "serve.grow", "serve.dispatch", "serve.wait", "serve.harvest",
+         "serve.retire", "frontend.pump", "frontend.intake")
 
 
 class RequestState(str, enum.Enum):
@@ -557,7 +567,13 @@ class SlotServeEngine:
         self.finished: List[ServeRequest] = []
         self.grant_log: List[int] = []                 # rids in grant order
         self.step_clock = 0
+        self.rounds = 0          # calls of step(): the serve.step number
         self.decode_dispatches = 0
+        #: lanes the round programs computed (bucket rows x decode
+        #: steps, plus bucket rows x chunk width when a chunk rides) and
+        #: those that produced a token or a prompt position
+        self.lanes_dispatched = 0
+        self.lanes_used = 0
         self.pauses = 0          # slot-rounds a lazy top-up had to wait
         self.preemptions = 0     # lazy-overflow evictions (restart victims)
         self.prefix_hits = 0     # admissions that adopted a live prefix
@@ -970,16 +986,18 @@ class SlotServeEngine:
             return 0
         if self._admission_planner is None:
             return min(self.pool.n_free, n_queued)
-        now = float(self.step_clock)
-        act = sorted(self.active)                      # slot order
-        arr = ([now] * len(act)
-               + [now + 1e-3 * (i + 1) for i in range(n_queued)])
-        hold = ([float(max(self._steps_left[s], 1)) for s in act]
-                + [float(r.max_new_tokens) for r in self.queue])
-        n_plan = min(len(arr), self.plan_window)
-        _, _, waited = self._admission_planner(
-            np.asarray(arr[:n_plan], np.float32),
-            np.asarray(hold[:n_plan], np.float32))
+        with TraceAnnotation("serve.plan"):
+            now = float(self.step_clock)
+            act = sorted(self.active)                      # slot order
+            arr = ([now] * len(act)
+                   + [now + 1e-3 * (i + 1) for i in range(n_queued)])
+            hold = ([float(max(self._steps_left[s], 1)) for s in act]
+                    + [float(r.max_new_tokens) for r in self.queue])
+            n_plan = min(len(arr), self.plan_window)
+            # the planner returns host arrays: this call waits for it
+            _, _, waited = self._admission_planner(
+                np.asarray(arr[:n_plan], np.float32),
+                np.asarray(hold[:n_plan], np.float32))
         waited_q = waited[len(act):]
         # FIFO prefix of queued requests granted without waiting
         n_admit = 0
@@ -1110,101 +1128,105 @@ class SlotServeEngine:
         from each other (the donor's pages exist only after its
         insert); the index warms for the next round.
         """
-        if self.prefill_chunk:
-            return self._admit_chunked()
         had_decoders = bool(self.active)
         n_admit = self._planned_admit_count()
-        staged = []    # (req, slot, lp, bucket, reserve, grant, sh_ids,
-        #                 sh_len, from_cache)
-        staged_pages = 0
-        evict_groups: List[np.ndarray] = []   # cache LRU leaves to drop
-        evict_credit = 0                      # pages those drops free
-        lazy = self.kv_layout == "paged" and self.page_growth == "lazy"
-        while len(staged) < n_admit and self.queue and self.pool.n_free:
-            req = self.queue[0]
-            lp = int(req.prompt.size)
-            bucket = self._bucket_len(lp)
-            # worst-case flat positions (prompt bucket ∪ prompt+new+1):
-            # reserved now under eager growth (decode never allocates
-            # mid-dispatch), merely bounded under lazy growth. Either
-            # way a page-starved FIFO head waits for retirements to
-            # reclaim pages — later requests do not jump it.
-            reserve = max(bucket, lp + req.max_new_tokens + 1)
-            # lazy initial grant: the prefill bucket plus the first
-            # lookahead window, never past what the request can actually
-            # touch — short requests only ever hold pages they can fill
-            need = max(lp + req.max_new_tokens - 1, lp)
-            grant = (max(bucket,
-                         min(bucket + self.decode_chunk
-                             * self.page_lookahead_chunks, need))
-                     if lazy else reserve)
-            sh_len, sh_ids, from_cache = self._lookup_prefix(
-                req.prompt, bucket, 0)
-            n_shared = 0 if sh_ids is None else int(sh_ids.size)
-            if self.kv_layout == "paged":
-                def fits(extra: int) -> bool:
-                    return (self.pool.can_admit_lazy(
-                                grant, reserve,
-                                headroom_pages=self._headroom_pages(),
-                                pending_pages=staged_pages,
-                                shared_pages=n_shared, extra_free=extra)
-                            if lazy else
-                            self.pool.can_reserve(
-                                reserve, pending_pages=staged_pages,
-                                shared_pages=n_shared, extra_free=extra))
+        if self.prefill_chunk:
+            with TraceAnnotation("serve.grant"):
+                return self._admit_chunked(n_admit)
+        # staging (prefix lookups, slot grants) and the one allocator
+        # critical section; the prefills after it are model dispatches
+        with TraceAnnotation("serve.grant"):
+            staged = []    # (req, slot, lp, bucket, reserve, grant, sh_ids,
+            #                 sh_len, from_cache)
+            staged_pages = 0
+            evict_groups: List[np.ndarray] = []   # cache LRU leaves to drop
+            evict_credit = 0                      # pages those drops free
+            lazy = self.kv_layout == "paged" and self.page_growth == "lazy"
+            while len(staged) < n_admit and self.queue and self.pool.n_free:
+                req = self.queue[0]
+                lp = int(req.prompt.size)
+                bucket = self._bucket_len(lp)
+                # worst-case flat positions (prompt bucket ∪ prompt+new+1):
+                # reserved now under eager growth (decode never allocates
+                # mid-dispatch), merely bounded under lazy growth. Either
+                # way a page-starved FIFO head waits for retirements to
+                # reclaim pages — later requests do not jump it.
+                reserve = max(bucket, lp + req.max_new_tokens + 1)
+                # lazy initial grant: the prefill bucket plus the first
+                # lookahead window, never past what the request can actually
+                # touch — short requests only ever hold pages they can fill
+                need = max(lp + req.max_new_tokens - 1, lp)
+                grant = (max(bucket,
+                             min(bucket + self.decode_chunk
+                                 * self.page_lookahead_chunks, need))
+                         if lazy else reserve)
+                sh_len, sh_ids, from_cache = self._lookup_prefix(
+                    req.prompt, bucket, 0)
+                n_shared = 0 if sh_ids is None else int(sh_ids.size)
+                if self.kv_layout == "paged":
+                    def fits(extra: int) -> bool:
+                        return (self.pool.can_admit_lazy(
+                                    grant, reserve,
+                                    headroom_pages=self._headroom_pages(),
+                                    pending_pages=staged_pages,
+                                    shared_pages=n_shared, extra_free=extra)
+                                if lazy else
+                                self.pool.can_reserve(
+                                    reserve, pending_pages=staged_pages,
+                                    shared_pages=n_shared, extra_free=extra))
 
-                def credit() -> int:
-                    return self._evict_credit(
-                        evict_groups,
-                        [t[6] for t in staged] + [sh_ids])
-                evict_credit = credit()
-                if not fits(evict_credit):
-                    # short on pages: ask the cache's LRU to cover the
-                    # worst-case deficit — the drops ride this batch's
-                    # reserve_batch (no extra acquire)
-                    deficit = (
-                        max(self.pool.pages.pages_for(grant) - n_shared, 0)
-                        + staged_pages + self._headroom_pages()
-                        - self.pool.pages.n_free - evict_credit)
-                    groups, _ = self._plan_evictions(deficit)
-                    evict_groups.extend(groups)
-                    if not fits(credit()):
-                        break
-            self.queue.popleft()
-            # Algorithm-5 wait(): never blocks here because the kernel
-            # only granted as many requests as there are free slots —
-            # the planner and the gate agree by construction.
-            if not self.admission.acquire_slot(timeout=5.0):
-                self.queue.appendleft(req)
-                break
-            slot = self.pool.acquire(req.rid)
-            staged.append((req, slot, lp, bucket, reserve, grant,
-                           sh_ids, sh_len, from_cache))
-            if self.kv_layout == "paged":
-                staged_pages += max(
-                    self.pool.pages.pages_for(grant) - n_shared, 0)
-        if not staged:
-            # planned evictions must still land (the trie already
-            # forgot them): they ride the round's retirement batch
-            self._deferred_free.extend(evict_groups)
-            return 0
-
-        # one allocator critical section for the whole admission batch
-        # (private grants, shared-prefix increfs, AND cache-eviction
-        # decrefs together)
-        if self.kv_layout == "paged":
-            try:
-                grants = self.pool.reserve_batch(
-                    [(slot, grant)
-                     for (_, slot, _, _, _, grant, _, _, _) in staged],
-                    shared=[sh_ids for (*_, sh_ids, _, _) in staged],
-                    evict=evict_groups or None)
-            except InjectedFault:
-                self._abort_admission([(t[0], t[1]) for t in staged],
-                                      evict_groups)
+                    def credit() -> int:
+                        return self._evict_credit(
+                            evict_groups,
+                            [t[6] for t in staged] + [sh_ids])
+                    evict_credit = credit()
+                    if not fits(evict_credit):
+                        # short on pages: ask the cache's LRU to cover the
+                        # worst-case deficit — the drops ride this batch's
+                        # reserve_batch (no extra acquire)
+                        deficit = (
+                            max(self.pool.pages.pages_for(grant) - n_shared, 0)
+                            + staged_pages + self._headroom_pages()
+                            - self.pool.pages.n_free - evict_credit)
+                        groups, _ = self._plan_evictions(deficit)
+                        evict_groups.extend(groups)
+                        if not fits(credit()):
+                            break
+                self.queue.popleft()
+                # Algorithm-5 wait(): never blocks here because the kernel
+                # only granted as many requests as there are free slots —
+                # the planner and the gate agree by construction.
+                if not self.admission.acquire_slot(timeout=5.0):
+                    self.queue.appendleft(req)
+                    break
+                slot = self.pool.acquire(req.rid)
+                staged.append((req, slot, lp, bucket, reserve, grant,
+                               sh_ids, sh_len, from_cache))
+                if self.kv_layout == "paged":
+                    staged_pages += max(
+                        self.pool.pages.pages_for(grant) - n_shared, 0)
+            if not staged:
+                # planned evictions must still land (the trie already
+                # forgot them): they ride the round's retirement batch
+                self._deferred_free.extend(evict_groups)
                 return 0
-        else:
-            grants = [None] * len(staged)
+
+            # one allocator critical section for the whole admission batch
+            # (private grants, shared-prefix increfs, AND cache-eviction
+            # decrefs together)
+            if self.kv_layout == "paged":
+                try:
+                    grants = self.pool.reserve_batch(
+                        [(slot, grant)
+                         for (_, slot, _, _, _, grant, _, _, _) in staged],
+                        shared=[sh_ids for (*_, sh_ids, _, _) in staged],
+                        evict=evict_groups or None)
+                except InjectedFault:
+                    self._abort_admission([(t[0], t[1]) for t in staged],
+                                          evict_groups)
+                    return 0
+            else:
+                grants = [None] * len(staged)
 
         instant = []               # eos/0-budget on the prefill token
         for (req, slot, lp, bucket, reserve, grant,
@@ -1266,7 +1288,7 @@ class SlotServeEngine:
             self.decode_rounds_stalled_by_prefill += 1
         return len(staged)
 
-    def _admit_chunked(self) -> int:
+    def _admit_chunked(self, n_admit: int) -> int:
         """Chunked-mode admission: pure bookkeeping, NO model dispatch.
 
         A granted request gets a slot, pages for its first chunk(s), and
@@ -1292,7 +1314,6 @@ class SlotServeEngine:
         last chunk always stays private — the completion logits must
         come from a chunk this engine runs.
         """
-        n_admit = self._planned_admit_count()
         staged = []       # (req, slot, lp, grant, sh_ids, sh_len, from_cache)
         staged_pages = 0
         evict_groups: List[np.ndarray] = []
@@ -1733,44 +1754,47 @@ class SlotServeEngine:
         decref/free — cancellation-deferred pages ride this same
         critical section). Returns the number of still-active
         requests."""
-        self._apply_cancels()
-        self._expire_queued()
-        if self.kv_layout == "paged":
-            # between rounds, never mid-critical-section (the adaptive
-            # mutex contract); a no-op for pinned/auto wait modes
-            self.pool.retune()
-        self._admit()
-        if not self.active:
-            self._flush_deferred_frees()
-            return 0
-        # round-level recovery (DESIGN.md §15): an injected fault rolls
-        # the round back (the PRNG key is the only host state the
-        # dispatch section had consumed) and retries with linear
-        # backoff; repeated faults blamed on one request quarantine
-        # exactly that request. The attempt cap bounds even an
-        # always-faulting run: every failure advances some rid's
-        # streak, so quarantines drain the active set before it trips.
-        # Any other error — a compile error, device memory exhausted —
-        # is not transient: it propagates to the caller untouched.
-        attempts = 0
-        max_attempts = self.quarantine_after * (len(self.active) + 1)
-        while True:
-            try:
-                n = self._run_round()
-            except InjectedFault as exc:
-                attempts += 1
-                self.rounds_retried += 1
-                self._recover_round(exc)
-                if not self.active:
-                    self._flush_deferred_frees()
-                    return 0
-                if attempts >= max_attempts:
-                    raise
-                if self.retry_backoff_s > 0:
-                    time.sleep(self.retry_backoff_s * attempts)
-                continue
-            self._round_failures.clear()
-            return n
+        with StepTraceAnnotation("serve.step", step_num=self.rounds):
+            self.rounds += 1
+            self._apply_cancels()
+            self._expire_queued()
+            with TraceAnnotation("serve.admit"):
+                if self.kv_layout == "paged":
+                    # between rounds, never mid-critical-section (the adaptive
+                    # mutex contract); a no-op for pinned/auto wait modes
+                    self.pool.retune()
+                self._admit()
+            if not self.active:
+                self._flush_deferred_frees()
+                return 0
+            # round-level recovery (DESIGN.md §15): an injected fault rolls
+            # the round back (the PRNG key is the only host state the
+            # dispatch section had consumed) and retries with linear
+            # backoff; repeated faults blamed on one request quarantine
+            # exactly that request. The attempt cap bounds even an
+            # always-faulting run: every failure advances some rid's
+            # streak, so quarantines drain the active set before it trips.
+            # Any other error — a compile error, device memory exhausted —
+            # is not transient: it propagates to the caller untouched.
+            attempts = 0
+            max_attempts = self.quarantine_after * (len(self.active) + 1)
+            while True:
+                try:
+                    n = self._run_round()
+                except InjectedFault as exc:
+                    attempts += 1
+                    self.rounds_retried += 1
+                    self._recover_round(exc)
+                    if not self.active:
+                        self._flush_deferred_frees()
+                        return 0
+                    if attempts >= max_attempts:
+                        raise
+                    if self.retry_backoff_s > 0:
+                        time.sleep(self.retry_backoff_s * attempts)
+                    continue
+                self._round_failures.clear()
+                return n
 
     def _run_round(self) -> int:
         """The round body ``step``'s recovery loop drives: plan, grow,
@@ -1806,192 +1830,208 @@ class SlotServeEngine:
                 remaining={s: int(self._pf_end[s] - self._pf_pos[s])
                            for s in backlog}).chunk_rows
         if self.kv_layout == "paged":
-            paused, advancing = self._grow_for_chunk(steps, tuple(planned))
+            with TraceAnnotation("serve.grow"):
+                paused, advancing = self._grow_for_chunk(steps,
+                                                         tuple(planned))
         else:
             paused, advancing = set(), set(planned)
         if not self.active:                    # everything preempted away
             self._flush_deferred_frees()
             return 0
-        chunk_rows = [s for s in planned
-                      if s in advancing and s in self.active]
-        pf_rows = ([s for s in self.active if self._prefilling(s)]
-                   if chunked else [])
-        frozen = np.ones(self.capacity, bool)
-        for slot in self.active:
-            if slot not in paused and not self._prefilling(slot):
-                frozen[slot] = False
-        lens_before = np.asarray(self.pool.lens) if paused else None
-        view = self.pool.cache_view()
-        if paused:
-            # paused rows must not touch the arena this round: masking
-            # their block-table rows to sentinel drops their scatters
-            # (in particular into a still-shared page whose CoW split
-            # starved) and their frozen outputs never read anyway; the
-            # rolled-back length makes the resumed chunk rewrite every
-            # dropped position before its first read
-            view["pages"] = self.pool.masked_table(paused)
-        bucket_rows = None
-        if self.bucketed_dispatch:
-            # every active slot rides the bucket (prefilling/paused rows
-            # included: the decode-scan-then-chunk-scatter ordering
-            # invariant needs their lanes computed); vacant slots are
-            # pure scratch and stay out, shrinking the dispatch
-            kb = self._dispatch_cache.bucket(len(self.active))
-            bucket_rows = jnp.asarray(self._dispatch_cache.pad_rows(
-                sorted(self.active), kb))
-        # dispatch section: the PRNG split is the ONLY host state
-        # consumed before the jitted call returns, so restoring the key
-        # on failure rolls the whole section back — a retried round
-        # replays with the same key and (under greedy decoding) the
-        # same tokens
-        key0 = self._key
-        self._key, sub = jax.random.split(self._key)
-        try:
-            if self.fault_plan is not None:
-                self.fault_plan.dispatch(
-                    [r.rid for r in self.active.values()])
-            if chunked:
-                C = self.prefill_chunk
-                pf_tok = np.zeros((self.capacity, C), np.int32)
-                pf_qpos = np.zeros((self.capacity, C), np.int32)
-                pf_wpos = np.full((self.capacity, C), _DROP_POS, np.int32)
-                valid: Dict[int, int] = {}
-                for s in chunk_rows:
-                    p0 = int(self._pf_pos[s])
-                    v = int(min(C, self._pf_end[s] - p0))
-                    pf_tok[s, :v] = self.active[s].prompt[p0:p0 + v]
-                    pf_qpos[s, :] = p0 + np.arange(C)
-                    pf_wpos[s, :v] = p0 + np.arange(v)
-                    valid[s] = v
-                if bucket_rows is not None:
-                    cache, tok, toks, pf_logits = self._bucket_round(
-                        self.params, view, bucket_rows,
-                        jnp.asarray(self._last_tok), jnp.asarray(frozen),
-                        jnp.asarray(pf_tok), jnp.asarray(pf_qpos),
-                        jnp.asarray(pf_wpos), sub,
-                        steps=steps, chunk=C if chunk_rows else 0)
-                else:
-                    cache, tok, toks, pf_logits = self._round(
-                        self.params, view,
-                        jnp.asarray(self._last_tok), jnp.asarray(frozen),
-                        jnp.asarray(pf_tok), jnp.asarray(pf_qpos),
-                        jnp.asarray(pf_wpos), sub,
-                        steps=steps, chunk=C if chunk_rows else 0)
-            else:
-                if bucket_rows is not None:
-                    cache, tok, toks = self._bucket_chunk(
-                        self.params, view, bucket_rows,
-                        jnp.asarray(self._last_tok), jnp.asarray(frozen),
-                        sub, steps=steps)
-                else:
-                    cache, tok, toks = self._chunk(
-                        self.params, view,
-                        jnp.asarray(self._last_tok), jnp.asarray(frozen),
-                        sub, steps=steps)
-                pf_logits = None
-        except InjectedFault:
-            self._key = key0
-            raise
-        self.decode_dispatches += 1
-        self.pool.adopt(cache)
-        self._last_tok = np.array(tok)     # writable copy (inserts mutate)
-        toks = np.asarray(toks)                        # [steps, K]
-
-        # advance prefill cursors for the chunks that rode this dispatch
-        completions: List[Tuple[int, int]] = []
-        for s in chunk_rows:
-            v = valid[s]
-            self._pf_pos[s] += v
-            self.prefill_chunks += 1
-            self.prefill_tokens += v
-            self.pad_tokens += self.prefill_chunk - v
-            self.active[s].prefill_chunks += 1
-            if self._pf_pos[s] >= self._pf_end[s]:
-                completions.append((s, v))
-        if paused or pf_rows:
-            # roll lengths back: paused rows to before the dispatch,
-            # prefilling rows to their cursor (the decode scan advanced
-            # every row; its scratch writes for these rows land again —
-            # identically or rewritten — before anything reads them, so
-            # the length vector is the only state to rewind)
-            lens = np.array(self.pool.lens)
-            for s in pf_rows:
-                lens[s] = int(self._pf_pos[s])
+        with TraceAnnotation("serve.dispatch"):
+            chunk_rows = [s for s in planned
+                          if s in advancing and s in self.active]
+            pf_rows = ([s for s in self.active if self._prefilling(s)]
+                       if chunked else [])
+            frozen = np.ones(self.capacity, bool)
+            for slot in self.active:
+                if slot not in paused and not self._prefilling(slot):
+                    frozen[slot] = False
+            lens_before = np.asarray(self.pool.lens) if paused else None
+            view = self.pool.cache_view()
             if paused:
-                idx = list(paused)
-                lens[idx] = lens_before[idx]
-            self.pool.set_lens(jnp.asarray(lens))
+                # paused rows must not touch the arena this round: masking
+                # their block-table rows to sentinel drops their scatters
+                # (in particular into a still-shared page whose CoW split
+                # starved) and their frozen outputs never read anyway; the
+                # rolled-back length makes the resumed chunk rewrite every
+                # dropped position before its first read
+                view["pages"] = self.pool.masked_table(paused)
+            bucket_rows = None
+            if self.bucketed_dispatch:
+                # every active slot rides the bucket (prefilling/paused rows
+                # included: the decode-scan-then-chunk-scatter ordering
+                # invariant needs their lanes computed); vacant slots are
+                # pure scratch and stay out, shrinking the dispatch
+                kb = self._dispatch_cache.bucket(len(self.active))
+                bucket_rows = jnp.asarray(self._dispatch_cache.pad_rows(
+                    sorted(self.active), kb))
+            # dispatch section: the PRNG split is the ONLY host state
+            # consumed before the jitted call returns, so restoring the key
+            # on failure rolls the whole section back — a retried round
+            # replays with the same key and (under greedy decoding) the
+            # same tokens
+            key0 = self._key
+            self._key, sub = jax.random.split(self._key)
+            try:
+                if self.fault_plan is not None:
+                    self.fault_plan.dispatch(
+                        [r.rid for r in self.active.values()])
+                if chunked:
+                    C = self.prefill_chunk
+                    pf_tok = np.zeros((self.capacity, C), np.int32)
+                    pf_qpos = np.zeros((self.capacity, C), np.int32)
+                    pf_wpos = np.full((self.capacity, C), _DROP_POS, np.int32)
+                    valid: Dict[int, int] = {}
+                    for s in chunk_rows:
+                        p0 = int(self._pf_pos[s])
+                        v = int(min(C, self._pf_end[s] - p0))
+                        pf_tok[s, :v] = self.active[s].prompt[p0:p0 + v]
+                        pf_qpos[s, :] = p0 + np.arange(C)
+                        pf_wpos[s, :v] = p0 + np.arange(v)
+                        valid[s] = v
+                    if bucket_rows is not None:
+                        cache, tok, toks, pf_logits = self._bucket_round(
+                            self.params, view, bucket_rows,
+                            jnp.asarray(self._last_tok), jnp.asarray(frozen),
+                            jnp.asarray(pf_tok), jnp.asarray(pf_qpos),
+                            jnp.asarray(pf_wpos), sub,
+                            steps=steps, chunk=C if chunk_rows else 0)
+                    else:
+                        cache, tok, toks, pf_logits = self._round(
+                            self.params, view,
+                            jnp.asarray(self._last_tok), jnp.asarray(frozen),
+                            jnp.asarray(pf_tok), jnp.asarray(pf_qpos),
+                            jnp.asarray(pf_wpos), sub,
+                            steps=steps, chunk=C if chunk_rows else 0)
+                else:
+                    if bucket_rows is not None:
+                        cache, tok, toks = self._bucket_chunk(
+                            self.params, view, bucket_rows,
+                            jnp.asarray(self._last_tok), jnp.asarray(frozen),
+                            sub, steps=steps)
+                    else:
+                        cache, tok, toks = self._chunk(
+                            self.params, view,
+                            jnp.asarray(self._last_tok), jnp.asarray(frozen),
+                            sub, steps=steps)
+                    pf_logits = None
+            except InjectedFault:
+                self._key = key0
+                raise
+        self.decode_dispatches += 1
+        rows = (self.capacity if bucket_rows is None
+                else int(bucket_rows.shape[0]))
+        self.lanes_dispatched += rows * (
+            steps + (self.prefill_chunk if chunk_rows else 0))
+        # the host waits here for the round's results
+        with TraceAnnotation("serve.wait"):
+            self.pool.adopt(cache)
+            self._last_tok = np.array(tok)  # writable copy (inserts mutate)
+            toks = np.asarray(toks)                        # [steps, K]
 
-        retire: List[Tuple[int, int]] = []
-        pf_skip = set(pf_rows)
-        for s, v in completions:
-            # prompt fully cached: sample the first output token from
-            # the chunk's last real lane — the prefilling → decoding
-            # transition
-            req = self.active[s]
-            self._key, sub2 = jax.random.split(self._key)
-            tok0 = int(self._sample(pf_logits[s, v - 1][None, :], sub2)[0])
-            self._last_tok[s] = tok0
-            req.out_tokens.append(tok0)
-            if self.eos_id is not None and tok0 == self.eos_id:
-                req.eos = True
-            if self.kv_layout == "paged" and self.prefix_sharing:
-                self.prefix_index.register(
-                    req.prompt, 0,
-                    self.pool.page_ids(
-                        s, self.pool.pages.pages_for(int(self._pf_end[s]))),
-                    schedule=self.prefill_chunk)
-            self._pf_pos[s] = 0
-            self._pf_end[s] = 0
-            req.state = RequestState.DECODING
-            req.decode_start_step = self.step_clock
-            if req.eos or self._steps_left[s] <= 0:
-                retire.append((s, 0))
-        # live generated-boundary registration (§14): as a decoding
-        # conversation crosses page boundaries, its prompt+reply full
-        # pages enter the live index under the chunked key — a forked
-        # request (same history, new continuation) adopts them while
-        # the donor is still active. Chunked-key only (a fork's prompt
-        # length differs, so one-shot buckets would never match), and
-        # only with the cache on: its token-exactness contract (§14)
-        # covers decode-written pages; plain §11 sharing keeps its
-        # stricter bit-identical-by-construction tier.
-        reg_gen = (self.prefix_cache is not None and self.prefix_sharing
-                   and self.prefill_chunk > 0
-                   and self.kv_layout == "paged")
-        for slot in list(self.active):
-            if slot in paused or slot in pf_skip:
-                continue
-            req = self.active[slot]
-            done_at = None
-            for s in range(steps):
-                if self._steps_left[slot] <= 0:
-                    break
-                t = int(toks[s, slot])
-                req.out_tokens.append(t)
-                self._steps_left[slot] -= 1
-                if self.eos_id is not None and t == self.eos_id:
+        with TraceAnnotation("serve.harvest"):
+            # advance prefill cursors for the chunks that rode this dispatch
+            completions: List[Tuple[int, int]] = []
+            used = 0                  # lanes that computed something kept
+            for s in chunk_rows:
+                v = valid[s]
+                used += v
+                self._pf_pos[s] += v
+                self.prefill_chunks += 1
+                self.prefill_tokens += v
+                self.pad_tokens += self.prefill_chunk - v
+                self.active[s].prefill_chunks += 1
+                if self._pf_pos[s] >= self._pf_end[s]:
+                    completions.append((s, v))
+            if paused or pf_rows:
+                # roll lengths back: paused rows to before the dispatch,
+                # prefilling rows to their cursor (the decode scan advanced
+                # every row; its scratch writes for these rows land again —
+                # identically or rewritten — before anything reads them, so
+                # the length vector is the only state to rewind)
+                lens = np.array(self.pool.lens)
+                for s in pf_rows:
+                    lens[s] = int(self._pf_pos[s])
+                if paused:
+                    idx = list(paused)
+                    lens[idx] = lens_before[idx]
+                self.pool.set_lens(jnp.asarray(lens))
+
+            retire: List[Tuple[int, int]] = []
+            pf_skip = set(pf_rows)
+            for s, v in completions:
+                # prompt fully cached: sample the first output token from
+                # the chunk's last real lane — the prefilling → decoding
+                # transition
+                req = self.active[s]
+                self._key, sub2 = jax.random.split(self._key)
+                tok0 = int(self._sample(pf_logits[s, v - 1][None, :], sub2)[0])
+                self._last_tok[s] = tok0
+                req.out_tokens.append(tok0)
+                if self.eos_id is not None and tok0 == self.eos_id:
                     req.eos = True
-                    done_at = s + 1
-                    break
-                if self._steps_left[slot] <= 0:
-                    done_at = s + 1
-            if done_at is not None:
-                retire.append((slot, done_at))
-            elif reg_gen:
-                ps = self.pool.page_size
-                extent = int(req.prompt.size) + len(req.out_tokens) - 1
-                n_full = extent // ps
-                if n_full > int(self._gen_reg[slot]):
-                    written = np.concatenate(
-                        [req.prompt,
-                         np.asarray(req.out_tokens[:-1], np.int32)])
+                if self.kv_layout == "paged" and self.prefix_sharing:
                     self.prefix_index.register(
-                        written[:n_full * ps], 0,
-                        self.pool.page_ids(slot, n_full),
+                        req.prompt, 0,
+                        self.pool.page_ids(
+                            s,
+                            self.pool.pages.pages_for(int(self._pf_end[s]))),
                         schedule=self.prefill_chunk)
-                    self._gen_reg[slot] = n_full
-        self._retire_batch(retire)
+                self._pf_pos[s] = 0
+                self._pf_end[s] = 0
+                req.state = RequestState.DECODING
+                req.decode_start_step = self.step_clock
+                if req.eos or self._steps_left[s] <= 0:
+                    retire.append((s, 0))
+            # live generated-boundary registration (§14): as a decoding
+            # conversation crosses page boundaries, its prompt+reply full
+            # pages enter the live index under the chunked key — a forked
+            # request (same history, new continuation) adopts them while
+            # the donor is still active. Chunked-key only (a fork's prompt
+            # length differs, so one-shot buckets would never match), and
+            # only with the cache on: its token-exactness contract (§14)
+            # covers decode-written pages; plain §11 sharing keeps its
+            # stricter bit-identical-by-construction tier.
+            reg_gen = (self.prefix_cache is not None and self.prefix_sharing
+                       and self.prefill_chunk > 0
+                       and self.kv_layout == "paged")
+            for slot in list(self.active):
+                if slot in paused or slot in pf_skip:
+                    continue
+                req = self.active[slot]
+                done_at = None
+                for s in range(steps):
+                    if self._steps_left[slot] <= 0:
+                        break
+                    t = int(toks[s, slot])
+                    req.out_tokens.append(t)
+                    used += 1
+                    self._steps_left[slot] -= 1
+                    if self.eos_id is not None and t == self.eos_id:
+                        req.eos = True
+                        done_at = s + 1
+                        break
+                    if self._steps_left[slot] <= 0:
+                        done_at = s + 1
+                if done_at is not None:
+                    retire.append((slot, done_at))
+                elif reg_gen:
+                    ps = self.pool.page_size
+                    extent = int(req.prompt.size) + len(req.out_tokens) - 1
+                    n_full = extent // ps
+                    if n_full > int(self._gen_reg[slot]):
+                        written = np.concatenate(
+                            [req.prompt,
+                             np.asarray(req.out_tokens[:-1], np.int32)])
+                        self.prefix_index.register(
+                            written[:n_full * ps], 0,
+                            self.pool.page_ids(slot, n_full),
+                            schedule=self.prefill_chunk)
+                        self._gen_reg[slot] = n_full
+            self.lanes_used += used
+        with TraceAnnotation("serve.retire"):
+            self._retire_batch(retire)
         self.step_clock += steps
         return len(self.active)
 
@@ -2081,12 +2121,9 @@ class SlotServeEngine:
             "late_rows": float(sum(
                 r.past_deadline(self.step_clock, now_s)
                 for r in self.active.values())),
-            "semaphore_admitted": float(self.admission.admitted),
-            "semaphore_completed": float(self.admission.completed),
             # chunked-prefill ledger (meaningful in both modes: one-shot
             # pads prompts to buckets, chunked pads only the last chunk)
             "prefill_chunk_tokens": float(self.prefill_chunk),
-            "round_token_budget": float(self.round_token_budget),
             "prefill_tokens": float(self.prefill_tokens),
             "pad_tokens": float(self.pad_tokens),
             "pad_fraction": (
@@ -2095,6 +2132,10 @@ class SlotServeEngine:
             "prefill_chunks": float(self.prefill_chunks),
             "decode_rounds_stalled_by_prefill": float(
                 self.decode_rounds_stalled_by_prefill),
+            # round-program lanes computed, and those kept (a decode
+            # token, or a prompt position of a chunk)
+            "lanes_dispatched": float(self.lanes_dispatched),
+            "lanes_used": float(self.lanes_used),
         }
         # paged-attention read path + bucketed-dispatch ledger (§16):
         # retraces must be 0 in steady state — one trace per distinct
@@ -2135,8 +2176,6 @@ class SlotServeEngine:
                 "aborted_batches": float(pp.aborted_batches),
                 # what a one-lock-per-page allocator (the PR 3 baseline
                 # framing) would have paid for the same page traffic
-                "per_page_lock_acquires": float(
-                    pp.pages_alloced + pp.pages_freed),
                 "per_page_lock_acquires_per_token": (
                     float(pp.pages_alloced + pp.pages_freed)
                     / float(max(toks, 1))),

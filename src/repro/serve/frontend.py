@@ -58,6 +58,7 @@ import time
 from typing import Deque, Dict, List, Optional
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.serve.engine import RequestState, ServeRequest, SlotServeEngine
 from repro.serve.faults import FaultPlan, InjectedFault
@@ -338,20 +339,21 @@ class AsyncFrontend:
     def _pump(self) -> None:
         """Push freshly decoded tokens into each live stream and close
         the handles whose requests went terminal this round."""
-        now = time.perf_counter()
-        for rid in list(self._live):
-            h = self._live[rid]
-            req = h.req
-            toks = req.out_tokens
-            if len(toks) > h._streamed and not h._cancel_requested:
-                if h.first_token_s is None:
-                    h.first_token_s = now
-                for t in toks[h._streamed:]:
-                    h._queue.put_nowait(int(t))
-                h._streamed = len(toks)
-            if req.state.terminal:
-                self._finish_handle(h)
-                del self._live[rid]
+        with TraceAnnotation("frontend.pump"):
+            now = time.perf_counter()
+            for rid in list(self._live):
+                h = self._live[rid]
+                req = h.req
+                toks = req.out_tokens
+                if len(toks) > h._streamed and not h._cancel_requested:
+                    if h.first_token_s is None:
+                        h.first_token_s = now
+                    for t in toks[h._streamed:]:
+                        h._queue.put_nowait(int(t))
+                    h._streamed = len(toks)
+                if req.state.terminal:
+                    self._finish_handle(h)
+                    del self._live[rid]
 
     async def _drive(self) -> None:
         """The round pump. Each iteration: apply cancels, transfer
@@ -362,8 +364,9 @@ class AsyncFrontend:
         eng = self.engine
         try:
             while True:
-                self._apply_cancels()
-                self._transfer_intake()
+                with TraceAnnotation("frontend.intake"):
+                    self._apply_cancels()
+                    self._transfer_intake()
                 if eng.queue or eng.active or eng._cancel_pending:
                     if self._fault_plan is not None:
                         try:
