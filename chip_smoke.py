@@ -292,7 +292,8 @@ def run(opts, jax, jnp, dev, device) -> int:
               f"{int(st['decode_dispatches'])} rounds, "
               f"{int(st['prefix_hits'])} prefix hits, "
               f"{int(st['cow_splits'])} CoW splits, "
-              f"{int(st['dispatch_trace_keys'])} traced shapes; page "
+              f"{int(st['dispatch_trace_keys'])} traced shapes, "
+              f"attention_kv_grouped {st['attention_kv_grouped']:.1f}; page "
               f"arena {engine.pool.pages.num_pages} pages = "
               f"{arena_bytes} bytes")
         serve.enforce_leak_gate(engine)     # exits non-zero on a leak

@@ -473,6 +473,7 @@ def main(argv=None):
                 f"{int(st['dispatch_retraces'])} retraces)"
                 if engine.bucketed_dispatch else "")
         print(f"[serve] paged attention: impl={engine.attention_impl}, "
+              f"attention_kv_grouped {st['attention_kv_grouped']:.1f}, "
               f"bucketed dispatch {bd}{disp}")
         print(f"[serve] page arena: {pool.pages.num_pages} pages x "
               f"{pool.page_size} tokens, peak "

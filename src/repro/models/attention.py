@@ -5,10 +5,13 @@ see DESIGN.md §6): with TP = |model| = 16, several assigned archs have
 head counts that don't divide it (qwen3 40H, minitron 24H, whisper 12H,
 gemma3 4H). Plans, chosen per (arch, mesh) via ``head_plan``:
 
-  'shard'  heads % tp == 0 — shard heads; GQA handled by *expanding* K/V
-           to one head per query head (``expand_kv`` — the repeat_kv trick:
-           keeps every attention tensor rank-4 and head-sharded even when
-           kv_heads < tp, at per-device K/V cost equal to the original).
+  'shard'  heads % tp == 0 — shard heads. The full-sequence paths expand
+           K/V to one head per query head (``expand_kv`` — the repeat_kv
+           trick: keeps every attention tensor rank-4 and head-sharded
+           even when kv_heads < tp). The cached reads expand only where
+           that sharding needs it (kv_heads % tp != 0); otherwise they
+           contract the queries against the KV heads directly
+           (``kv_grouped``), so no [B, T, H, hd] K/V is ever built.
   'pad'    pad query heads with zeros to the next tp multiple when the
            waste is <= 1.5x (qwen3 40->48: 1.2x; minitron 24->32, whisper
            12->16: 1.33x). Correctness: padded heads produce garbage
@@ -29,7 +32,10 @@ dynamic-bound loops):
     query resident (seq-shardable; rectangle FLOPs).
   * ``banded_attention``   — gather a [S, W] band of K/V; exact window.
 
-Decode attends the full cache in one einsum (linear in cache length).
+Decode and chunked prefill attend the whole cache in one grouped einsum
+(linear in cache length): query head h reads kv head h // G, and each
+K/V position is read once per kv head whatever G is. On one device —
+every serving path — that is always the form taken.
 """
 
 from __future__ import annotations
@@ -89,9 +95,28 @@ def head_plan(n_heads: int, tp: Optional[int] = None) -> Tuple[str, int]:
     return "seq", n_heads
 
 
+def kv_grouped(n_heads: int, n_kv_heads: int, q_heads: int) -> bool:
+    """Whether the cached reads contract ``q_heads`` query heads against
+    the KV heads directly rather than expanding K/V to ``q_heads``.
+
+    True under a 'shard' plan with unpadded queries whose KV heads split
+    evenly over the tp degree of the active activation policy — always
+    on one device. Otherwise (a 'pad' or 'seq' plan, or fewer KV heads
+    than the tp degree, as in mesh lowerings) the expansion is what
+    keeps the attention tensors head-sharded, and it stays."""
+    plan, _ = head_plan(n_heads)
+    return (plan == "shard" and q_heads == n_heads
+            and n_kv_heads % current_tp() == 0)
+
+
 def expand_kv(kv: jax.Array, n_heads: int, pad_to: int = 0) -> jax.Array:
     """[B,T,KV,hd] -> [B,T,H(p),hd]: one K/V head per query head (+ zero
     heads for padding).
+
+    Kept for head-sharded lowerings only: the full-sequence paths under
+    a mesh, and the cached reads where ``kv_grouped`` says no. It
+    multiplies the K/V bytes by G, so a single-device cached read never
+    takes it.
 
     Implemented as broadcast+reshape (kv-major head layout, h -> kv = h//g)
     rather than ``jnp.take``: a gather over a sharded kv-head axis makes
@@ -406,35 +431,50 @@ def scatter_page_tokens(arena: jax.Array, pages: jax.Array, pos: jax.Array,
     return arena.at[page, pos % ps].set(val.astype(arena.dtype), mode="drop")
 
 
+def _grouped_attend(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
+                    valid: jax.Array) -> jax.Array:
+    """q [B,C,H,hd] against k/v [B,T,KV,hd] (H a multiple of KV; KV == H
+    for an expanded cache) under ``valid`` [B or 1, C, T]. Query head h
+    reads kv head h // G (kv-major, as ``expand_kv`` and the fused
+    kernel): q is viewed as [B,C,KV,G,hd] and contracted against the
+    KV heads, so each cached K/V position is read once per kv head.
+    Rounding is the expanded form's: scores and output in the cache's
+    dtype, the softmax in float32."""
+    b, c, h, hd = q.shape
+    kvh = k_cache.shape[2]
+    g = h // kvh
+    scale = 1.0 / math.sqrt(hd)
+
+    qg = (q * scale).reshape(b, c, kvh, g, hd)
+    sc = jnp.einsum("bckgd,btkd->bckgt", qg, k_cache).astype(jnp.float32)
+    sc = jnp.where(valid[:, :, None, None, :], sc, NEG_INF)
+    p = jax.nn.softmax(sc, axis=-1)
+    out = jnp.einsum("bckgt,btkd->bckgd", p.astype(v_cache.dtype), v_cache)
+    return out.reshape(b, c, h, hd).astype(q.dtype)
+
+
 def decode_attention(
-    q: jax.Array,            # [B, 1, Hp, hd]
-    k_cache: jax.Array,      # [B, T, Hp, hd] (pre-expanded/padded)
+    q: jax.Array,            # [B, 1, H(p), hd]
+    k_cache: jax.Array,      # [B, T, KV, hd], or [B, T, Hp, hd] expanded
     v_cache: jax.Array,
     cache_len: jax.Array,
     *,
     window: Optional[int] = None,
 ) -> jax.Array:
     """Single-token attention over the cache (linear in T)."""
-    b, _, h, hd = q.shape
     t = k_cache.shape[1]
-    scale = 1.0 / math.sqrt(hd)
-
-    sc = jnp.einsum("bqhd,bthd->bqht", q * scale, k_cache).astype(jnp.float32)
     pos = jnp.arange(t)
     cl = jnp.asarray(cache_len)
     cl = cl[:, None] if cl.ndim == 1 else cl[None, None]
     valid = pos[None, :] < cl
     if window is not None:
         valid &= pos[None, :] >= cl - window
-    sc = jnp.where(valid[:, None, None, :], sc, NEG_INF)
-    p = jax.nn.softmax(sc, axis=-1)
-    out = jnp.einsum("bqht,bthd->bqhd", p.astype(v_cache.dtype), v_cache)
-    return out.astype(q.dtype)
+    return _grouped_attend(q, k_cache, v_cache, valid[:, None, :])
 
 
 def chunk_decode_attention(
-    q: jax.Array,            # [B, C, Hp, hd]
-    k_cache: jax.Array,      # [B, T, Hp, hd] (pre-expanded/padded)
+    q: jax.Array,            # [B, C, H(p), hd]
+    k_cache: jax.Array,      # [B, T, KV, hd], or [B, T, Hp, hd] expanded
     v_cache: jax.Array,
     q_positions: jax.Array,  # [B, C] absolute position of each query
     *,
@@ -450,20 +490,13 @@ def chunk_decode_attention(
     views are per-row). Pad lanes of a partial last chunk carry garbage
     positions; their outputs are discarded and their writes dropped by
     the engine, so they never influence a real lane."""
-    b, c, h, hd = q.shape
     t = k_cache.shape[1]
-    scale = 1.0 / math.sqrt(hd)
-
-    sc = jnp.einsum("bqhd,bthd->bqht", q * scale, k_cache).astype(jnp.float32)
     pos = jnp.arange(t)
     qp = jnp.asarray(q_positions)
     valid = pos[None, None, :] <= qp[:, :, None]                 # [B, C, T]
     if window is not None:
         valid &= pos[None, None, :] > qp[:, :, None] - window
-    sc = jnp.where(valid[:, :, None, :], sc, NEG_INF)
-    p = jax.nn.softmax(sc, axis=-1)
-    out = jnp.einsum("bqht,bthd->bqhd", p.astype(v_cache.dtype), v_cache)
-    return out.astype(q.dtype)
+    return _grouped_attend(q, k_cache, v_cache, valid)
 
 
 # --------------------------------------------------------------- dispatch
@@ -487,13 +520,19 @@ def full_attention(p, cfg, q, k, v, *, causal: bool,
     return kv_chunked_attention(q, ke, ve, causal=causal)
 
 
+def _cache_heads(cfg, q, k_cache, v_cache):
+    """The cache as the read contracts it: as stored ([.., KV, hd]) where
+    ``kv_grouped`` holds, else expanded to q's (tp-aligned) heads."""
+    h, hq = cfg.num_heads, q.shape[2]
+    if kv_grouped(h, k_cache.shape[2], hq):
+        return k_cache, v_cache
+    return expand_kv(k_cache, h, pad_to=hq), expand_kv(v_cache, h, pad_to=hq)
+
+
 def cached_decode_attention(p, cfg, q, k_cache, v_cache, cache_len, *,
                             window: Optional[int]) -> jax.Array:
-    h = cfg.num_heads
-    hq = q.shape[2]
-    ke = expand_kv(k_cache, h, pad_to=hq)
-    ve = expand_kv(v_cache, h, pad_to=hq)
-    return decode_attention(q, ke, ve, cache_len, window=window)
+    k, v = _cache_heads(cfg, q, k_cache, v_cache)
+    return decode_attention(q, k, v, cache_len, window=window)
 
 
 def paged_decode_attention(p, cfg, q, k_arena, v_arena, pages, cache_len, *,
@@ -505,7 +544,9 @@ def paged_decode_attention(p, cfg, q, k_arena, v_arena, pages, cache_len, *,
     contiguous [B, P*ps, KV, hd] view, then attend exactly as the
     contiguous layout (same masking, same per-row length semantics).
     Every gathered page round-trips HBM twice — once for the gather's
-    materialized view, once for attention to read it back.
+    materialized view, once for attention to read it back (once per kv
+    head: on one device the G query heads of a group are contracted
+    against it together, ``kv_grouped``).
 
     ``impl="fused"``: one Pallas kernel walks the block table per
     (row, kv-head) and computes online-softmax attention in a single
@@ -533,11 +574,8 @@ def paged_decode_attention(p, cfg, q, k_arena, v_arena, pages, cache_len, *,
 
 def cached_chunk_attention(p, cfg, q, k_cache, v_cache, q_positions, *,
                            window: Optional[int]) -> jax.Array:
-    h = cfg.num_heads
-    hq = q.shape[2]
-    ke = expand_kv(k_cache, h, pad_to=hq)
-    ve = expand_kv(v_cache, h, pad_to=hq)
-    return chunk_decode_attention(q, ke, ve, q_positions, window=window)
+    k, v = _cache_heads(cfg, q, k_cache, v_cache)
+    return chunk_decode_attention(q, k, v, q_positions, window=window)
 
 
 def paged_chunk_attention(p, cfg, q, k_arena, v_arena, pages, q_positions, *,

@@ -51,6 +51,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.profiler import StepTraceAnnotation, TraceAnnotation
 
+from repro.models import attention as attn
 from repro.serve.dispatch import DecodeDispatchCache
 from repro.serve.faults import FaultPlan, InjectedFault
 from repro.serve.kv_pages import PagedSlotPool, PrefixIndex
@@ -405,6 +406,9 @@ class SlotServeEngine:
         self.pad_prompts_to = pad_prompts_to
         self.kv_layout = kv_layout
         self.attention_impl = attention_impl
+        # the form the round program's gather reads took, recorded when
+        # the decode step is traced (``_chunk_impl``)
+        self.kv_grouped = False
         self.sync = sync if sync is not None else SyncLibrary.host_default()
         # the planning trace holds all K in-flight requests plus the
         # queued front; a window smaller than capacity would silently
@@ -664,6 +668,9 @@ class SlotServeEngine:
         chunk so over-generation past eos never reaches the caller.
         """
         eos = self.eos_id
+        cfg = self.model.cfg
+        self.kv_grouped = not cfg.attention_free and attn.kv_grouped(
+            cfg.num_heads, cfg.num_kv_heads, attn.head_plan(cfg.num_heads)[1])
 
         def body(carry, key_s):
             cache, tok, frozen = carry
@@ -2143,6 +2150,9 @@ class SlotServeEngine:
         # buckets times the chunk ∈ {0, C} variants
         out.update({
             "attention_fused": float(self.attention_impl == "fused"),
+            # 1.0: the gather reads (decode unless fused, and every
+            # chunk) contracted against the KV heads, no expanded K/V
+            "attention_kv_grouped": float(self.kv_grouped),
             "bucketed_dispatch": float(self.bucketed_dispatch),
             "dispatch_traces": float(
                 self._dispatch_cache.traces

@@ -19,6 +19,7 @@ occupancy-churn trace through ``DecodeDispatchCache``-bucketed rounds
 compiles a bounded bucket set and never retraces after warmup.
 """
 
+import dataclasses
 import types
 
 import jax
@@ -246,6 +247,18 @@ def test_engine_fused_without_bucketing(lm_setup):
     assert eng.stats()["dispatch_traces"] == 0.0
     for rid in ref:
         assert ref[rid]["out"] == got[rid]["out"]
+
+
+def test_engine_reports_grouped_kv_read(lm_setup):
+    """On one device the round program's gather reads contract against
+    the KV heads, and the engine's counter says so once a round has
+    been traced."""
+    cfg, _, _ = lm_setup
+    cfg = dataclasses.replace(cfg, num_kv_heads=cfg.num_heads // 2)
+    model = build_model(cfg)
+    params = model.init(jax.random.PRNGKey(0))
+    _, eng = _drive(model, params, 2, cfg.vocab_size, impl="gather")
+    assert eng.stats()["attention_kv_grouped"] == 1.0
 
 
 def test_engine_ctor_validation(lm_setup):
