@@ -38,10 +38,9 @@ SRC = Path(__file__).resolve().parent / "src"
 ARCH = "qwen3-14b"
 # Depth cut: 12 of 40 layers. The compile rehearsal's memory_analysis
 # for one v5e (16 GB) puts the largest round program (8 rows, a
-# 128-token prefill chunk) at 13.25 GiB: 8.83 GiB of weights plus the
-# 1.125 GiB page arena three times over (the round's cache argument is
-# not donated, so input, loop carry and output arena coexist) plus
-# logits. A 13th layer, or a larger arena, leaves no headroom.
+# 128-token prefill chunk) at 11.24 GiB: 8.83 GiB of weights, the
+# 1.125 GiB page arena once (the round program takes the cache over and
+# updates it in place), the chunk's logits and the temporaries.
 NUM_LAYERS = 12
 NUM_PAGES = 1536            # x 16 tokens x 12 layers: 1.125 GiB of KV
 CAPACITY = 8
@@ -112,13 +111,14 @@ def read_paths(jax, jnp, cfg):
 
     @jax.jit
     def both(k, v, q, pages, lens):
-        k, v = k[-1], v[-1]
+        last = k.shape[0] - 1         # read by index, as the layer scan does
         fused, gather = (paged_decode_attention(
-            None, cfg, q, k, v, pages, lens, window=None, impl=impl)
-            for impl in ("fused", "gather"))
+            None, cfg, q, k, v, pages, lens, window=None, impl=impl,
+            layer=last) for impl in ("fused", "gather"))
         wrong_head = paged_decode_attention(
-            None, cfg, q, jnp.roll(k, 1, axis=-2), jnp.roll(v, 1, axis=-2),
-            pages, lens, window=None, impl="fused")
+            None, cfg, q, jnp.roll(k[last], 1, axis=-2),
+            jnp.roll(v[last], 1, axis=-2), pages, lens, window=None,
+            impl="fused")
         return fused, gather, wrong_head
     return both
 
@@ -293,7 +293,8 @@ def run(opts, jax, jnp, dev, device) -> int:
               f"{int(st['prefix_hits'])} prefix hits, "
               f"{int(st['cow_splits'])} CoW splits, "
               f"{int(st['dispatch_trace_keys'])} traced shapes, "
-              f"attention_kv_grouped {st['attention_kv_grouped']:.1f}; page "
+              f"attention_kv_grouped {st['attention_kv_grouped']:.1f}, "
+              f"arena_in_place {st['arena_in_place']:.1f}; page "
               f"arena {engine.pool.pages.num_pages} pages = "
               f"{arena_bytes} bytes")
         serve.enforce_leak_gate(engine)     # exits non-zero on a leak

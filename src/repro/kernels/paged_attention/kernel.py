@@ -18,12 +18,15 @@ walks the row's live block-table entries in flat position order — page
 softmax ``(m, l, acc)`` per query head. GQA is what makes the fusion
 pay: all ``G`` queries of a group score against one page load.
 
-Memory layout: the block table and the row lengths are scalar-prefetch
-operands (SMEM), so page ids are scalars the kernel indexes HBM with.
-The K/V arenas stay in HBM (``memory_space=pl.ANY``); each loop step
-DMAs one whole page ``[ps, KV, hd]`` into VMEM scratch and waits for
-it. A page is the smallest legal copy: one kv head's ``[ps, hd]``
-slice cuts the second-minor KV axis to 1, which the TPU tiling refuses,
+Memory layout: the layer index, the block table and the row lengths
+are scalar-prefetch operands (SMEM), so page ids are scalars the kernel
+indexes HBM with. The K/V arenas stay in HBM (``memory_space=pl.ANY``),
+layer-stacked ``[L, num_pages, ps, KV, hd]`` as the model's layer scan
+carries them, so no layer's arena is sliced out for the call; each loop
+step DMAs one whole page ``[ps, KV, hd]`` of the layer into VMEM
+scratch and waits for it. A page is the smallest legal copy: one kv
+head's ``[ps, hd]`` slice cuts the second-minor KV axis to 1, which the
+TPU tiling refuses,
 and viewing the arena as ``[pages, ps, KV * hd]`` makes XLA relayout
 the whole arena on every call. In VMEM the page is flattened to
 ``[ps * KV, hd]`` and every query head scores against all of it, with
@@ -68,11 +71,12 @@ NEG_INF = -2.0e38  # matches models/attention.py's masking sentinel
 
 
 def paged_decode_kernel(
+    layer_ref,    # SMEM (1,) i32: the layer of the stacked arenas
     pages_ref,    # SMEM (B * P,) i32: flattened block tables
     len_ref,      # SMEM (B,) i32: per-row cache lengths
     q_ref,        # (1, H, hd): the row's queries, kv-major (H = KV * G)
-    k_hbm,        # HBM (num_pages, ps, KV, hd): K arena
-    v_hbm,        # HBM (num_pages, ps, KV, hd): V arena
+    k_hbm,        # HBM (L, num_pages, ps, KV, hd): K arenas
+    v_hbm,        # HBM (L, num_pages, ps, KV, hd): V arenas
     o_ref,        # out (1, H, hd)
     k_buf,        # VMEM scratch (ps, KV, hd): one page, every kv head
     v_buf,        # VMEM scratch (ps, KV, hd)
@@ -86,6 +90,7 @@ def paged_decode_kernel(
     scale: float,
 ):
     row = pl.program_id(0)
+    layer = layer_ref[0]
     n_heads, hd = q_ref.shape[1], q_ref.shape[2]
     kv = n_heads // group
     width = page_size * kv                  # flattened page: lane c is
@@ -104,8 +109,10 @@ def paged_decode_kernel(
         page = pages_ref[row * table_len + j]
         live = page < num_pages                   # sentinel page -> all masked
         pid = jnp.clip(page, 0, num_pages - 1)
-        k_copy = pltpu.make_async_copy(k_hbm.at[pid], k_buf, sem.at[0])
-        v_copy = pltpu.make_async_copy(v_hbm.at[pid], v_buf, sem.at[1])
+        k_copy = pltpu.make_async_copy(k_hbm.at[layer, pid], k_buf,
+                                       sem.at[0])
+        v_copy = pltpu.make_async_copy(v_hbm.at[layer, pid], v_buf,
+                                       sem.at[1])
         k_copy.start()
         v_copy.start()
         k_copy.wait()
@@ -145,17 +152,22 @@ def paged_decode_kernel(
 
 def fused_paged_decode(
     q: jax.Array,          # [B, KV, G, hd] kv-major grouped queries
-    k_arena: jax.Array,    # [num_pages, ps, KV, hd]
-    v_arena: jax.Array,    # [num_pages, ps, KV, hd]
+    k_arena: jax.Array,    # [num_pages, ps, KV, hd], or [L, ...] stacked
+    v_arena: jax.Array,    # same shape as k_arena
     pages: jax.Array,      # [B, P] i32 block tables (sentinel = num_pages)
     cache_len: jax.Array,  # [B] i32 per-row lengths
     *,
+    layer: Optional[jax.Array] = None,
     window: Optional[int] = None,
     interpret: Optional[bool] = None,
 ) -> jax.Array:
-    """One-pass block-table decode attention. Returns [B, KV, G, hd]."""
+    """One-pass block-table decode attention. Returns [B, KV, G, hd].
+    ``layer`` picks the layer of layer-stacked arenas; without it the
+    arenas hold one layer."""
+    if layer is None:
+        k_arena, v_arena, layer = k_arena[None], v_arena[None], 0
     b, kv, g, hd = q.shape
-    num_pages, ps = k_arena.shape[0], k_arena.shape[1]
+    num_pages, ps = k_arena.shape[1], k_arena.shape[2]
     p_cap = pages.shape[1]
     scale = 1.0 / math.sqrt(hd)
 
@@ -165,7 +177,7 @@ def fused_paged_decode(
     arena = pl.BlockSpec(memory_space=pl.ANY)
     rows = pl.BlockSpec((1, kv * g, hd), lambda i, *_: (i, 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3,
         grid=(b,),
         in_specs=[rows, arena, arena],
         out_specs=rows,
@@ -183,6 +195,7 @@ def fused_paged_decode(
             dimension_semantics=("parallel",),
         ),
         interpret=kernels.resolve_interpret(interpret),
-    )(pages.astype(jnp.int32).reshape(-1), cache_len.astype(jnp.int32),
+    )(jnp.asarray(layer, jnp.int32).reshape(1),
+      pages.astype(jnp.int32).reshape(-1), cache_len.astype(jnp.int32),
       q.reshape(b, kv * g, hd), k_arena, v_arena)
     return out.reshape(b, kv, g, hd)

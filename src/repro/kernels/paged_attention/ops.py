@@ -3,7 +3,9 @@
 Bridges the decode path's shapes — q ``[B, 1, Hq, hd]`` (possibly
 head-padded for tensor parallelism), arena ``[num_pages, ps, KV, hd]``,
 block table ``[B, P]``, per-row lengths ``[B]`` — to the kernel's
-kv-major ``[B, KV, G, hd]`` grouping and back. Under a 'pad' head plan
+kv-major ``[B, KV, G, hd]`` grouping and back. A layer-stacked arena
+``[L, num_pages, ps, KV, hd]`` comes with its ``layer`` index and is
+handed to the kernel whole. Under a 'pad' head plan
 the padded query heads are dropped before the kernel and re-padded
 with zeros after: the output projection masks their ``wo`` rows to
 zero, so zeros are exactly what the gather path computes for them too.
@@ -26,13 +28,14 @@ from .kernel import fused_paged_decode
 
 def paged_decode_fused(
     q: jax.Array,          # [B, 1, Hq, hd] (Hq >= H when head-padded)
-    k_arena: jax.Array,    # [num_pages, ps, KV, hd]
-    v_arena: jax.Array,    # [num_pages, ps, KV, hd]
+    k_arena: jax.Array,    # [num_pages, ps, KV, hd], or [L, ...] stacked
+    v_arena: jax.Array,    # same shape as k_arena
     pages: jax.Array,      # [B, P] i32
     cache_len: jax.Array,  # [B] i32 (or scalar; broadcast per row)
     n_heads: int,
     *,
     window: Optional[int] = None,
+    layer: Optional[jax.Array] = None,
     interpret: Optional[bool] = None,
 ) -> jax.Array:
     """Fused one-pass paged decode attention. Returns [B, 1, Hq, hd],
@@ -40,7 +43,7 @@ def paged_decode_fused(
     b, s, hq, hd = q.shape
     if s != 1:
         raise ValueError("fused paged decode is single-token (q [B,1,H,hd])")
-    kv = k_arena.shape[2]
+    kv = k_arena.shape[-2]
     if n_heads % kv:
         raise ValueError(f"num_heads {n_heads} not divisible by "
                          f"num_kv_heads {kv}")
@@ -51,7 +54,7 @@ def paged_decode_fused(
     # kv-major grouping: expanded head h reads kv head h // g, so the
     # true heads reshape directly to [B, KV, G, hd]
     qg = q[:, 0, :n_heads, :].reshape(b, kv, g, hd)
-    out = fused_paged_decode(qg, k_arena, v_arena, pages, cl,
+    out = fused_paged_decode(qg, k_arena, v_arena, pages, cl, layer=layer,
                              window=window, interpret=interpret)
     out = out.reshape(b, 1, n_heads, hd)
     if hq > n_heads:

@@ -474,6 +474,7 @@ def main(argv=None):
                 if engine.bucketed_dispatch else "")
         print(f"[serve] paged attention: impl={engine.attention_impl}, "
               f"attention_kv_grouped {st['attention_kv_grouped']:.1f}, "
+              f"arena_in_place {st['arena_in_place']:.1f}, "
               f"bucketed dispatch {bd}{disp}")
         print(f"[serve] page arena: {pool.pages.num_pages} pages x "
               f"{pool.page_size} tokens, peak "

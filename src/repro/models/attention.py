@@ -360,13 +360,25 @@ def banded_attention(
 PAGED_ATTENTION_SCOPE = "paged_attention"
 
 
-def gather_pages(arena: jax.Array, pages: jax.Array) -> jax.Array:
+def layer_prefix(layer) -> tuple:
+    """Index prefix selecting one layer of a layer-stacked cache leaf
+    (``[n_periods, ...]``, as the LM's layer scan carries it), or the
+    empty prefix for a leaf that holds one layer alone."""
+    return () if layer is None else (layer,)
+
+
+def gather_pages(arena: jax.Array, pages: jax.Array,
+                 layer: Optional[jax.Array] = None) -> jax.Array:
     """[num_pages, ps, ...] arena + [B, P] block table -> [B, P*ps, ...]
     per-row contiguous view. Sentinel/unallocated entries clip to the
     last page; its contents are garbage for this row but lie beyond the
-    row's true length, so the decode mask hides them."""
-    num_pages = arena.shape[0]
-    g = jnp.take(arena, jnp.clip(pages, 0, num_pages - 1), axis=0)
+    row's true length, so the decode mask hides them. With ``layer``
+    the arena is layer-stacked ([L, num_pages, ps, ...]) and the view
+    is read as ``arena[layer, table]`` in one gather: the layer's arena
+    is never sliced out on its own."""
+    lead = layer_prefix(layer)
+    num_pages = arena.shape[len(lead)]
+    g = arena[lead + (jnp.clip(pages, 0, num_pages - 1),)]
     b, np_, ps = g.shape[:3]
     return g.reshape((b, np_ * ps) + g.shape[3:])
 
@@ -390,7 +402,8 @@ def copy_pages(arena: jax.Array, src: jax.Array, dst: jax.Array,
 
 
 def scatter_page_token(arena: jax.Array, pages: jax.Array, pos: jax.Array,
-                       val: jax.Array) -> jax.Array:
+                       val: jax.Array,
+                       layer: Optional[jax.Array] = None) -> jax.Array:
     """Write ``val[b]`` at flat position ``pos[b]`` of row b's paged
     cache. arena [num_pages, ps, ...]; pages [B, P]; pos [B]; val [B, ...].
     Writes addressed past the block table or into sentinel (unallocated)
@@ -400,19 +413,15 @@ def scatter_page_token(arena: jax.Array, pages: jax.Array, pos: jax.Array,
     *post-split* one: a row whose write would land in a shared
     (refcount > 1) page is either split before the dispatch or has its
     table row sentinel-masked for the round, so a scatter can never
-    write a page another slot still reads."""
-    num_pages, ps = arena.shape[0], arena.shape[1]
-    p_cap = pages.shape[1]
-    page_idx = pos // ps
-    page = jnp.take_along_axis(
-        pages, jnp.clip(page_idx, 0, p_cap - 1)[:, None], axis=1)[:, 0]
-    # out-of-table positions (and sentinel pages >= num_pages) must miss
-    page = jnp.where((page_idx >= 0) & (page_idx < p_cap), page, num_pages)
-    return arena.at[page, pos % ps].set(val.astype(arena.dtype), mode="drop")
+    write a page another slot still reads. ``layer`` as in
+    :func:`scatter_page_tokens`."""
+    return scatter_page_tokens(arena, pages, pos[:, None], val[:, None],
+                               layer)
 
 
 def scatter_page_tokens(arena: jax.Array, pages: jax.Array, pos: jax.Array,
-                        val: jax.Array) -> jax.Array:
+                        val: jax.Array,
+                        layer: Optional[jax.Array] = None) -> jax.Array:
     """Chunk form of :func:`scatter_page_token`: write ``val[b, c]`` at
     flat position ``pos[b, c]`` of row b's paged cache. arena
     [num_pages, ps, ...]; pages [B, P]; pos [B, C]; val [B, C, ...].
@@ -421,14 +430,19 @@ def scatter_page_tokens(arena: jax.Array, pages: jax.Array, pos: jax.Array,
     because JAX wraps negative indices) or in a sentinel table entry
     drop, exactly as the single-token scatter. Within one chunk the
     engine feeds strictly increasing positions per row, so no two lanes
-    alias one (page, offset) cell."""
-    num_pages, ps = arena.shape[0], arena.shape[1]
+    alias one (page, offset) cell. With ``layer`` the arena is
+    layer-stacked ([L, num_pages, ps, ...]) and the values land in
+    ``arena[layer, page, offset]``: one scatter into the stacked arena,
+    which XLA updates in place."""
+    lead = layer_prefix(layer)
+    num_pages, ps = arena.shape[len(lead)], arena.shape[len(lead) + 1]
     p_cap = pages.shape[1]
     page_idx = pos // ps                                         # [B, C]
     page = jnp.take_along_axis(
         pages, jnp.clip(page_idx, 0, p_cap - 1), axis=1)         # [B, C]
     page = jnp.where((page_idx >= 0) & (page_idx < p_cap), page, num_pages)
-    return arena.at[page, pos % ps].set(val.astype(arena.dtype), mode="drop")
+    return arena.at[lead + (page, pos % ps)].set(val.astype(arena.dtype),
+                                                 mode="drop")
 
 
 def _grouped_attend(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
@@ -537,7 +551,8 @@ def cached_decode_attention(p, cfg, q, k_cache, v_cache, cache_len, *,
 
 def paged_decode_attention(p, cfg, q, k_arena, v_arena, pages, cache_len, *,
                            window: Optional[int],
-                           impl: str = "gather") -> jax.Array:
+                           impl: str = "gather",
+                           layer: Optional[jax.Array] = None) -> jax.Array:
     """Block-table decode, two executable implementations:
 
     ``impl="gather"`` (the reference): gather each row's pages into a
@@ -557,7 +572,9 @@ def paged_decode_attention(p, cfg, q, k_arena, v_arena, pages, cache_len, *,
     gather path; serving selects it via
     ``SlotServeEngine(attention_impl="fused")``.
 
-    Both run under the ``PAGED_ATTENTION_SCOPE`` name scope.
+    Both run under the ``PAGED_ATTENTION_SCOPE`` name scope. ``layer``
+    indexes layer-stacked arenas ([L, num_pages, ps, KV, hd]): both
+    paths read the layer's pages straight from the stacked arena.
     """
     if impl not in ("gather", "fused"):
         raise ValueError(f"unknown paged decode impl {impl!r}; "
@@ -565,9 +582,10 @@ def paged_decode_attention(p, cfg, q, k_arena, v_arena, pages, cache_len, *,
     with jax.named_scope(PAGED_ATTENTION_SCOPE):
         if impl == "fused":
             return paged_decode_fused(q, k_arena, v_arena, pages, cache_len,
-                                      cfg.num_heads, window=window)
-        kb = gather_pages(k_arena, pages)
-        vb = gather_pages(v_arena, pages)
+                                      cfg.num_heads, window=window,
+                                      layer=layer)
+        kb = gather_pages(k_arena, pages, layer)
+        vb = gather_pages(v_arena, pages, layer)
         return cached_decode_attention(p, cfg, q, kb, vb, cache_len,
                                        window=window)
 
@@ -579,14 +597,15 @@ def cached_chunk_attention(p, cfg, q, k_cache, v_cache, q_positions, *,
 
 
 def paged_chunk_attention(p, cfg, q, k_arena, v_arena, pages, q_positions, *,
-                          window: Optional[int]) -> jax.Array:
+                          window: Optional[int],
+                          layer: Optional[jax.Array] = None) -> jax.Array:
     """Block-table chunked prefill: gather the row's pages into position
     order, then attend at each query's absolute position (same gathered
     view and masking family as ``paged_decode_attention``, and the same
-    name scope)."""
+    name scope, and the same ``layer``)."""
     with jax.named_scope(PAGED_ATTENTION_SCOPE):
-        kb = gather_pages(k_arena, pages)
-        vb = gather_pages(v_arena, pages)
+        kb = gather_pages(k_arena, pages, layer)
+        vb = gather_pages(v_arena, pages, layer)
         return cached_chunk_attention(p, cfg, q, kb, vb, q_positions,
                                       window=window)
 

@@ -66,7 +66,7 @@ def block_forward(p, x, cfg, kind: str, use_moe: bool, positions,
 
 
 def block_decode(p, x, cache, cache_len, cfg, kind: str, use_moe: bool,
-                 pages=None, attn_impl: str = "gather"
+                 pages=None, attn_impl: str = "gather", layer=None
                  ) -> Tuple[jax.Array, Dict]:
     """One-token pass. x [B,1,D]; cache entry as built by block_forward
     (k/v padded to max length for attention layers).
@@ -87,13 +87,25 @@ def block_decode(p, x, cache, cache_len, cfg, kind: str, use_moe: bool,
     executable reference) or ``"fused"`` (one-pass Pallas block-table
     walk, kernels/paged_attention, DESIGN.md §16). Contiguous-layout
     decode ignores it.
+
+    ``layer`` (a traced int32 scalar) says the cache leaves are the
+    layer-stacked ones the LM's layer scan carries (``[n_periods, ...]``
+    ahead of every shape above): the block writes its new K/V or state
+    into that layer's cells of the stacked leaves and reads the layer
+    back from them, so the stacked cache is updated in place and no
+    whole-arena slice or write-back exists. ``None``: the leaves hold
+    this layer alone (the unrolled leftover layers).
     """
     cl = jnp.asarray(cache_len)
+    lead = attn.layer_prefix(layer)
     h = rmsnorm(p["ln1"], x, cfg.norm_eps)
     if kind == "mamba":
         y, state = mb.mamba_decode_step(
-            p["mixer"], h, (cache["conv"], cache["h"]), cfg)
+            p["mixer"], h, (cache["conv"][lead], cache["h"][lead]), cfg)
         new_cache = {"conv": state[0], "h": state[1]}
+        if layer is not None:
+            new_cache = {n: cache[n].at[layer].set(new_cache[n])
+                         for n in new_cache}
     else:
         if cl.ndim == 1:
             positions = cl[:, None].astype(jnp.int32)
@@ -103,25 +115,31 @@ def block_decode(p, x, cache, cache_len, cfg, kind: str, use_moe: bool,
         if pages is not None:
             if cl.ndim != 1:
                 raise ValueError("paged decode requires per-row [B] lens")
-            k_cache = attn.scatter_page_token(cache["k"], pages, cl, k[:, 0])
-            v_cache = attn.scatter_page_token(cache["v"], pages, cl, v[:, 0])
+            k_cache = attn.scatter_page_token(cache["k"], pages, cl,
+                                              k[:, 0], layer)
+            v_cache = attn.scatter_page_token(cache["v"], pages, cl,
+                                              v[:, 0], layer)
             y = attn.paged_decode_attention(
                 p["mixer"], cfg, q, k_cache, v_cache, pages, cl + 1,
-                window=_window_for(cfg, kind), impl=attn_impl)
+                window=_window_for(cfg, kind), impl=attn_impl, layer=layer)
         else:
             if cl.ndim == 1:
                 rows = jnp.arange(x.shape[0])
-                k_cache = cache["k"].at[rows, cl].set(
+                k_cache = cache["k"].at[lead + (rows, cl)].set(
                     k[:, 0].astype(cache["k"].dtype), mode="drop")
-                v_cache = cache["v"].at[rows, cl].set(
+                v_cache = cache["v"].at[lead + (rows, cl)].set(
                     v[:, 0].astype(cache["v"].dtype), mode="drop")
             else:
-                k_cache = jax.lax.dynamic_update_slice_in_dim(
-                    cache["k"], k.astype(cache["k"].dtype), cache_len, axis=1)
-                v_cache = jax.lax.dynamic_update_slice_in_dim(
-                    cache["v"], v.astype(cache["v"].dtype), cache_len, axis=1)
+                start = lead + (0, cl, 0, 0)
+                unit = (1,) * len(lead)
+                k_cache = jax.lax.dynamic_update_slice(
+                    cache["k"], k.astype(cache["k"].dtype).reshape(
+                        unit + k.shape), start)
+                v_cache = jax.lax.dynamic_update_slice(
+                    cache["v"], v.astype(cache["v"].dtype).reshape(
+                        unit + v.shape), start)
             y = attn.cached_decode_attention(
-                p["mixer"], cfg, q, k_cache, v_cache, cl + 1,
+                p["mixer"], cfg, q, k_cache[lead], v_cache[lead], cl + 1,
                 window=_window_for(cfg, kind))
         y = attn.attention_out(p["mixer"], y, cfg.num_heads)
         new_cache = {"k": k_cache, "v": v_cache}
@@ -138,7 +156,7 @@ def block_decode(p, x, cache, cache_len, cfg, kind: str, use_moe: bool,
 
 
 def block_prefill_chunk(p, x, cache, cfg, kind: str, use_moe: bool,
-                        positions, write_pos, pages=None
+                        positions, write_pos, pages=None, layer=None
                         ) -> Tuple[jax.Array, Dict]:
     """Chunked-prefill pass: C prompt tokens per row against the cache.
 
@@ -159,6 +177,7 @@ def block_prefill_chunk(p, x, cache, cfg, kind: str, use_moe: bool,
     Attention layers only: Mamba prefill is recurrent (state at i needs
     the state at i-1, not the cache), so chunking it is a different
     algorithm — the engine gates chunked mode to attention-pure archs.
+    ``layer`` indexes layer-stacked cache leaves as in ``block_decode``.
     """
     if kind == "mamba":
         raise ValueError("chunked prefill requires attention layers — "
@@ -167,19 +186,22 @@ def block_prefill_chunk(p, x, cache, cfg, kind: str, use_moe: bool,
     h = rmsnorm(p["ln1"], x, cfg.norm_eps)
     q, k, v = attn.qkv_project(p["mixer"], cfg, h, positions)
     if pages is not None:
-        k_cache = attn.scatter_page_tokens(cache["k"], pages, write_pos, k)
-        v_cache = attn.scatter_page_tokens(cache["v"], pages, write_pos, v)
+        k_cache = attn.scatter_page_tokens(cache["k"], pages, write_pos, k,
+                                           layer)
+        v_cache = attn.scatter_page_tokens(cache["v"], pages, write_pos, v,
+                                           layer)
         y = attn.paged_chunk_attention(
             p["mixer"], cfg, q, k_cache, v_cache, pages, positions,
-            window=_window_for(cfg, kind))
+            window=_window_for(cfg, kind), layer=layer)
     else:
+        lead = attn.layer_prefix(layer)
         rows = jnp.arange(x.shape[0])[:, None]
-        k_cache = cache["k"].at[rows, write_pos].set(
+        k_cache = cache["k"].at[lead + (rows, write_pos)].set(
             k.astype(cache["k"].dtype), mode="drop")
-        v_cache = cache["v"].at[rows, write_pos].set(
+        v_cache = cache["v"].at[lead + (rows, write_pos)].set(
             v.astype(cache["v"].dtype), mode="drop")
         y = attn.cached_chunk_attention(
-            p["mixer"], cfg, q, k_cache, v_cache, positions,
+            p["mixer"], cfg, q, k_cache[lead], v_cache[lead], positions,
             window=_window_for(cfg, kind))
     y = attn.attention_out(p["mixer"], y, cfg.num_heads)
     x = x + y

@@ -294,6 +294,43 @@ class LM:
               else jnp.asarray(0, jnp.int32))
         return {"periods": periods, "leftover": leftover, "len": ln}
 
+    def _scan_layers(self, params, cache, x, layer_fn):
+        """Run ``layer_fn`` over the stacked periods under one layer scan
+        with the ``periods`` cache in the carry: the scan runs over the
+        period parameters and the layer index, and each layer updates
+        its cells of the stacked cache leaves in place (``layer_fn``
+        gets the stacked leaves and the index, ``blocks.block_decode``).
+        So no layer's cache is sliced out and written back, and a
+        donated cache is updated where it lies. Returns (x, the periods
+        cache)."""
+        def body(carry, xs):
+            x, period_cache = carry
+            period_params, layer = xs
+            period_cache = dict(period_cache)
+            for j, (kind, use_moe) in enumerate(self.layout):
+                name = f"layer_{j}"
+                x, period_cache[name] = layer_fn(
+                    period_params[name], x, period_cache[name], kind,
+                    use_moe, layer)
+            return (x, period_cache), None
+
+        (x, period_cache), _ = jax.lax.scan(
+            body, (x, cache["periods"]),
+            (params["periods"], jnp.arange(self.n_periods, dtype=jnp.int32)))
+        return x, period_cache
+
+    def _leftover_layers(self, params, cache, x, layer_fn):
+        """The unrolled remainder layers, each on its own cache leaves
+        (``layer`` None). Returns (x, the leftover cache)."""
+        out = {}
+        for j in range(len(self.leftover)):
+            kind, use_moe = self.layout[j]
+            name = f"layer_{j}"
+            x, out[name] = layer_fn(params["leftover"][name], x,
+                                    cache["leftover"][name], kind, use_moe,
+                                    None)
+        return x, out
+
     def prefill_chunk(self, params, cache, tokens, positions, write_pos
                       ) -> Tuple[jax.Array, PyTree]:
         """One chunk of continuous (chunked) prefill against the decode
@@ -317,28 +354,14 @@ class LM:
             x = x * jnp.sqrt(jnp.asarray(cfg.d_model, x.dtype))
         x = constrain_batch(x)
 
-        def scan_body(x, pc):
-            period_params, period_cache = pc
-            new_caches = {}
-            for j, (kind, use_moe) in enumerate(self.layout):
-                x, nc = blocks.block_prefill_chunk(
-                    period_params[f"layer_{j}"], x,
-                    period_cache[f"layer_{j}"], cfg, kind, use_moe,
-                    positions, write_pos, pages=pages)
-                new_caches[f"layer_{j}"] = nc
-            return x, new_caches
+        def layer_fn(pp, x, lc, kind, use_moe, layer):
+            return blocks.block_prefill_chunk(
+                pp, x, lc, cfg, kind, use_moe, positions, write_pos,
+                pages=pages, layer=layer)
 
-        x, new_period_caches = jax.lax.scan(
-            scan_body, x, (params["periods"], cache["periods"]))
+        x, new_period_caches = self._scan_layers(params, cache, x, layer_fn)
 
-        new_leftover = {}
-        for j in range(len(self.leftover)):
-            kind, use_moe = self.layout[j]
-            x, nc = blocks.block_prefill_chunk(
-                params["leftover"][f"layer_{j}"], x,
-                cache["leftover"][f"layer_{j}"], cfg, kind, use_moe,
-                positions, write_pos, pages=pages)
-            new_leftover[f"layer_{j}"] = nc
+        x, new_leftover = self._leftover_layers(params, cache, x, layer_fn)
 
         x = rmsnorm(params["final_norm"], x, cfg.norm_eps)
         logits = self._unembed(params, x)                # [B, C, V]
@@ -375,28 +398,14 @@ class LM:
                 x = x[:, None, :]
         x = constrain_batch(x)
 
-        def scan_body(x, pc):
-            period_params, period_cache = pc
-            new_caches = {}
-            for j, (kind, use_moe) in enumerate(self.layout):
-                x, nc = blocks.block_decode(
-                    period_params[f"layer_{j}"], x,
-                    period_cache[f"layer_{j}"], cache_len, cfg, kind, use_moe,
-                    pages=pages, attn_impl=attn_impl)
-                new_caches[f"layer_{j}"] = nc
-            return x, new_caches
+        def layer_fn(pp, x, lc, kind, use_moe, layer):
+            return blocks.block_decode(
+                pp, x, lc, cache_len, cfg, kind, use_moe, pages=pages,
+                attn_impl=attn_impl, layer=layer)
 
-        x, new_period_caches = jax.lax.scan(
-            scan_body, x, (params["periods"], cache["periods"]))
+        x, new_period_caches = self._scan_layers(params, cache, x, layer_fn)
 
-        new_leftover = {}
-        for j in range(len(self.leftover)):
-            kind, use_moe = self.layout[j]
-            x, nc = blocks.block_decode(
-                params["leftover"][f"layer_{j}"], x,
-                cache["leftover"][f"layer_{j}"], cache_len, cfg, kind, use_moe,
-                pages=pages, attn_impl=attn_impl)
-            new_leftover[f"layer_{j}"] = nc
+        x, new_leftover = self._leftover_layers(params, cache, x, layer_fn)
 
         x = rmsnorm(params["final_norm"], x, cfg.norm_eps)
         logits = self._unembed(params, x)[:, 0]

@@ -578,6 +578,10 @@ class SlotServeEngine:
         #: those that produced a token or a prompt position
         self.lanes_dispatched = 0
         self.lanes_used = 0
+        #: dispatches after which the view's donated arena leaves were
+        #: gone: the round program took the arena over and updated it
+        #: in place (``stats()["arena_in_place"]``)
+        self.arena_in_place = 0
         self.pauses = 0          # slot-rounds a lazy top-up had to wait
         self.preemptions = 0     # lazy-overflow evictions (restart victims)
         self.prefix_hits = 0     # admissions that adopted a live prefix
@@ -631,13 +635,20 @@ class SlotServeEngine:
         self._key = jax.random.PRNGKey(seed)
         self._prefill = jax.jit(self._prefill_impl,
                                 static_argnames=("pad_to",))
-        self._chunk = jax.jit(self._chunk_impl, static_argnames=("steps",))
+        # every round program takes the cache over (donation): the arena
+        # it returns is the one it was handed, updated in place, and the
+        # view handed in is dead once the call is made (DESIGN.md §18)
+        self._chunk = jax.jit(self._chunk_impl, static_argnames=("steps",),
+                              donate_argnames=("cache",))
         self._round = jax.jit(self._round_impl,
-                              static_argnames=("steps", "chunk"))
+                              static_argnames=("steps", "chunk"),
+                              donate_argnames=("cache",))
         self._bucket_chunk = jax.jit(self._bucket_chunk_impl,
-                                     static_argnames=("steps",))
+                                     static_argnames=("steps",),
+                                     donate_argnames=("cache",))
         self._bucket_round = jax.jit(self._bucket_round_impl,
-                                     static_argnames=("steps", "chunk"))
+                                     static_argnames=("steps", "chunk"),
+                                     donate_argnames=("cache",))
 
     # ------------------------------------------------------------ jitted fns
     def _prefill_impl(self, params, tokens, length, *, pad_to):
@@ -1881,6 +1892,10 @@ class SlotServeEngine:
             key0 = self._key
             self._key, sub = jax.random.split(self._key)
             try:
+                # an injected fault strikes before the round program is
+                # called: the view is donated to that call, so only a
+                # fault raised ahead of it leaves the arena intact for the
+                # retried round
                 if self.fault_plan is not None:
                     self.fault_plan.dispatch(
                         [r.rid for r in self.active.values()])
@@ -1936,6 +1951,9 @@ class SlotServeEngine:
             self.pool.adopt(cache)
             self._last_tok = np.array(tok)  # writable copy (inserts mutate)
             toks = np.asarray(toks)                        # [steps, K]
+        donated = {k: v for k, v in view.items() if k not in ("len", "pages")}
+        self.arena_in_place += all(
+            x.is_deleted() for x in jax.tree_util.tree_leaves(donated))
 
         with TraceAnnotation("serve.harvest"):
             # advance prefill cursors for the chunks that rode this dispatch
@@ -2143,6 +2161,10 @@ class SlotServeEngine:
             # token, or a prompt position of a chunk)
             "lanes_dispatched": float(self.lanes_dispatched),
             "lanes_used": float(self.lanes_used),
+            # share of round dispatches whose donated arena the round
+            # program took over (1.0: updated in place, never copied)
+            "arena_in_place": (float(self.arena_in_place)
+                               / float(max(self.decode_dispatches, 1))),
         }
         # paged-attention read path + bucketed-dispatch ledger (§16):
         # retraces must be 0 in steady state — one trace per distinct

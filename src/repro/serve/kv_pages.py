@@ -1304,7 +1304,13 @@ class PagedSlotPool:
         return self.pages.retune()
 
     def cache_view(self) -> PyTree:
-        """Model-cache form: arena leaves + 'len' vector + block table."""
+        """Model-cache form: arena leaves + 'len' vector + block table.
+
+        The view shares the pool's arena and length buffers, and the
+        engine's round programs take it over (donation): the dispatch
+        consumes it, the arena is updated in place, and the pool's own
+        references are dead until :meth:`adopt` hands the round's result
+        back. Nothing may read the pool's arena or lengths in between."""
         out = dict(self.arena)
         out["len"] = self.lens
         out["pages"] = jnp.asarray(self._tables)

@@ -166,7 +166,10 @@ class SlotPool:
         self.lens = self.lens.at[int(slot)].set(int(length))
 
     def cache_view(self) -> PyTree:
-        """The arena in model-cache form (arena leaves + 'len' vector)."""
+        """The arena in model-cache form (arena leaves + 'len' vector).
+        Consumed by the round dispatch it is handed to, as
+        ``PagedSlotPool.cache_view``: only :meth:`adopt` hands the arena
+        back."""
         out = dict(self.arena)
         out["len"] = self.lens
         return out

@@ -296,6 +296,40 @@ def test_round_retry_preserves_survivor_streams(lm_setup):
     assert _survivors_match(base, res) == len(base)
 
 
+def test_dispatch_fault_leaves_the_donated_arena_intact(lm_setup):
+    """The round program takes the arena over (donation), so a dispatch
+    fault must strike before the call: the retried round then reads an
+    intact arena. Dispatch faults alone: every retried round replays the
+    fault-free run's tokens, the pool's arena is live after every round,
+    ``check()`` passes, and every dispatch that ran took the arena over
+    (``arena_in_place`` 1.0)."""
+    cfg, model, params = lm_setup
+    base, _, _ = _drive(model, params, None, vocab=cfg.vocab_size)
+
+    fp = FaultPlan(11, dispatch_rate=0.3)
+    events = gen_trace(7, n_requests=6, vocab=cfg.vocab_size,
+                       max_prompt=12, max_new=6, p_cancel=0.0)
+    eng = _chaos_engine(model, params, fp)
+    step = eng.step
+
+    def checked_step():
+        n = step()
+        assert not any(x.is_deleted()
+                       for x in jax.tree_util.tree_leaves(eng.pool.arena))
+        eng.pool.check()
+        return n
+    eng.step = checked_step
+    res = drive_trace(eng, events)
+    st = eng.stats()
+    assert fp.injected > 0 and st["rounds_retried"] > 0
+    assert st["requests_quarantined"] == 0
+    assert st["arena_in_place"] == 1.0
+    assert _survivors_match(base, res) == len(base)
+    eng.drop_prefix_cache()
+    eng.pool.check()
+    assert eng.pool.pages.in_use == 0
+
+
 def test_poisoned_request_is_quarantined_alone(lm_setup):
     """A request that deterministically kills its round is FAILED after
     ``quarantine_after`` consecutive failures; every other request's

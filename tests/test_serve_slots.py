@@ -287,3 +287,36 @@ def test_admission_window_overflow_buckets_up():
     np.testing.assert_allclose(gw, np.asarray(g), rtol=1e-6)
     np.testing.assert_allclose(rw, np.asarray(r), rtol=1e-6)
     np.testing.assert_array_equal(ww, np.asarray(w))
+
+
+@pytest.mark.parametrize("layout,chunk", [("slots", None), ("slots", 4),
+                                          ("paged", None), ("paged", 4)])
+def test_round_program_takes_the_arena_over(lm_setup, layout, chunk):
+    """Each of the four round programs (unbucketed or bucketed, with or
+    without a prefill chunk) is handed the pool's cache and donated it:
+    after ``step`` the arena the view shared is deleted, the pool holds
+    the round's arena, and ``arena_in_place`` reads 1.0."""
+    cfg, model, params = lm_setup
+    kw = {"page_size": 4} if layout == "paged" else {}
+    eng = SlotServeEngine(model, params, capacity=2, max_len=48,
+                          decode_chunk=2, kv_layout=layout,
+                          prefill_chunk_tokens=chunk, **kw)
+    views = []
+    view_of = eng.pool.cache_view
+    eng.pool.cache_view = lambda: views.append(view_of()) or views[-1]
+    rng = np.random.default_rng(0)
+    for _ in range(3):
+        eng.submit(rng.integers(1, cfg.vocab_size, 6), max_new_tokens=5)
+    while eng.queue or eng.active:
+        n = len(views)
+        eng.step()
+        assert len(views) == n + 1            # one dispatch a round
+        arena = {k: v for k, v in views[-1].items()
+                 if k not in ("len", "pages")}
+        assert all(x.is_deleted() for x in jax.tree_util.tree_leaves(arena))
+        assert not any(x.is_deleted()
+                       for x in jax.tree_util.tree_leaves(eng.pool.arena))
+    st = eng.stats()
+    assert st["decode_dispatches"] == len(views) > 0
+    assert st["arena_in_place"] == 1.0
+    assert st["finished"] == 3

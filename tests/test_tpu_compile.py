@@ -142,3 +142,66 @@ def test_paged_decode_step_qwen3_full_width(one_chip, impl, monkeypatch):
     assert not expanded, expanded[:4]
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes + mem.output_size_in_bytes < 16e9
+
+
+@pytest.mark.parametrize("chunk", [0, 128])
+def test_round_program_updates_the_arena_in_place(one_chip, chunk):
+    """The engine's bucketed round program at the benchmark's shapes
+    (qwen3-14b widths, 11 layers, a 1536-page arena, 16 rows, two decode
+    steps, with and without a 128-token prefill chunk). The cache is
+    donated, so the compiled module aliases the K and V arenas to its
+    outputs; the layer scan carries them and scatters into them, so no
+    op copies an arena or writes one back whole (no arena-shaped copy
+    or dynamic-update-slice), and the temporaries stay below one arena
+    (K and V) beside the chunk round's own [16, 128, vocab] logits."""
+    from repro.serve.engine import SlotServeEngine
+    layers, pages, rows = 11, 1536, 16
+    cfg = dataclasses.replace(get_arch("qwen3-14b"), num_layers=layers,
+                              tie_embeddings=False)
+    model = build_model(cfg)
+    params = _shapes(one_chip, jax.eval_shape(model.init,
+                                              jax.random.PRNGKey(0)))
+    # an engine whose own arrays stay tiny: only the shapes below compile
+    tiny = jax.tree_util.tree_map(
+        lambda s: jnp.zeros((1,) * len(s.shape), s.dtype), params)
+    engine = SlotServeEngine(model, tiny, capacity=rows, max_len=784,
+                             kv_layout="paged", page_size=PS, num_pages=4,
+                             prefill_chunk_tokens=128, decode_chunk=2)
+    view = engine.pool.cache_view()
+    view["pages"] = jnp.zeros((rows, 98), jnp.int32)
+    view = _shapes(one_chip, jax.tree_util.tree_map(
+        lambda x: (jax.ShapeDtypeStruct((layers, pages) + x.shape[2:],
+                                        x.dtype)
+                   if x.ndim == 5 else x), view))
+    i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32, sharding=one_chip)
+    args = (params, view, i32(rows), i32(rows),
+            jax.ShapeDtypeStruct((rows,), jnp.bool_, sharding=one_chip),
+            i32(rows, 128), i32(rows, 128), i32(rows, 128),
+            _shapes(one_chip, jax.random.PRNGKey(0)))
+    compiled = engine._bucket_round.lower(*args, steps=2,
+                                          chunk=chunk).compile()
+    hlo = compiled.as_text()
+
+    arena_shape = rf"bf16\[(?:{layers},{pages},{PS}|{layers * pages * PS})" \
+                  rf",{KV},{HD}\]"
+    arena_params = re.findall(
+        rf"= {arena_shape}\{{[^}}]*\}} parameter\((\d+)\)", hlo)
+    aliased = re.search(r"input_output_alias=\{(.*?)\}, entry", hlo)
+    assert aliased, "the round program aliases nothing"
+    aliased = set(re.findall(r"\((\d+), \{\}", aliased.group(1)))
+    entry = [p for p in arena_params
+             if re.search(rf"cache__periods.*parameter\({p}\)", hlo)]
+    assert len(entry) == 2 and set(entry) <= aliased, (entry, aliased)
+
+    moved = [line.strip()[:160] for line in hlo.splitlines()
+             if re.search(rf"%[\w.-]*(?:copy|dynamic-update-slice)[\w.-]* = "
+                          rf"{arena_shape}", line)
+             or re.search(rf"= {arena_shape}\{{[^}}]*\}} "
+                          rf"(?:copy|dynamic-update-slice)\(", line)]
+    assert not moved, moved[:4]
+
+    mem = compiled.memory_analysis()
+    arena_bytes = 2 * layers * pages * PS * KV * HD * 2
+    assert mem.alias_size_in_bytes >= arena_bytes
+    logits_bytes = rows * chunk * cfg.vocab_size * 4
+    assert mem.temp_size_in_bytes < arena_bytes + logits_bytes
