@@ -168,8 +168,8 @@ def backend_latency_rows(
 ) -> Tuple[List[str], Dict[str, Dict[str, float]]]:
     """Per-primitive per-backend plan latency of the unified sync API.
 
-    The kernel/ref numbers are the post-compile hot-path cost the serving
-    scheduler pays per replanning round; the host number is the cost of
+    The kernel/ref numbers are the post-compile cost of one plan on a
+    warm compile cache; the host number is the cost of
     an *observed execution* with real threads (the equivalence oracle,
     never on a hot loop)."""
     from repro.sync import SyncLibrary

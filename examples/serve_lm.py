@@ -1,9 +1,9 @@
 """Slot-pool continuous batching example: N > K requests, FIFO-verified.
 
 Round-trips 12 concurrent requests through a 4-slot engine — the
-Algorithm-5 sleeping semaphore gates admission, the Pallas semaphore
-kernel plans each round's batch, and one fixed-shape batched decode
-serves all active slots per dispatch.
+Algorithm-5 sleeping semaphore gates admission, each round admits the
+FIFO prefix of the queue that the free slots hold, and one fixed-shape
+batched decode serves all active slots per dispatch.
 
     PYTHONPATH=src python examples/serve_lm.py
     PYTHONPATH=src python examples/serve_lm.py --kv-layout paged

@@ -51,14 +51,14 @@ def semaphore_admission_window(arrive_t, hold, *, capacity: int,
                                window: int = 32,
                                interpret: Optional[bool] = None,
                                use_kernel: bool = True):
-    """Fixed-shape admission planning for the serving hot loop.
+    """Fixed-shape admission planning for a scheduler that replans.
 
-    ``semaphore_admission`` compiles per input length; the slot engine
-    replans admission every scheduler round with a varying number of
-    in-flight + queued requests, which would retrace the kernel each
-    round. This wrapper (a ``repro.sync.window.WindowedPlanner``) pads
-    the trace to a fixed ``window`` and slices the padding back off, so
-    one compiled kernel serves every round. Bursts longer than the window
+    ``semaphore_admission`` compiles per input length; a scheduler that
+    replans admission as its number of in-flight + queued requests
+    varies would retrace the kernel for each length. This wrapper (a
+    ``repro.sync.window.WindowedPlanner``) pads the trace to a fixed
+    ``window`` and slices the padding back off, so one compiled kernel
+    serves every round. Bursts longer than the window
     bucket up to the next power-of-2 multiple — a bounded set of traced
     shapes — with a one-time warning instead of failing the hot loop.
 

@@ -2,9 +2,9 @@
 
 Drives the full serving path on a reduced config: one engine replica with
 a preallocated K-slot KV arena, the paper's Algorithm-5 sleeping
-semaphore as the admission gate, the Pallas semaphore kernel replanning
-the grant timeline every scheduler round, and one fixed-shape batched
-decode dispatch per round.
+semaphore as the admission gate (a round admits the FIFO prefix of the
+queue that the free slots hold), and one fixed-shape batched decode
+dispatch per round.
 
   python -m repro.launch.serve --arch qwen3-14b --smoke \
       --requests 32 --capacity 8 --new-tokens 16
@@ -21,11 +21,12 @@ prefill a fixed chunk per scheduler round *inside* the decode dispatch,
 under a ``--round-token-budget`` that funds decode rows first — so a
 long prompt never stalls in-flight decodes.
 The sync substrate is a CLI knob:
-``--sync-backend`` picks the admission planner's backend (interpret
-kernel / TPU hardware / pure-jnp ref) and ``--admission-sem`` the live
-gate's algorithm (the paper's sleeping FA semaphore vs the spin
-baselines) — both flow into the engine through one injected
-``SyncLibrary``.
+``--admission-sem`` picks the live gate's algorithm (the paper's
+sleeping FA semaphore vs the spin baselines), which flows into the
+engine through one injected ``SyncLibrary``; ``--sync-backend`` picks
+the backend (interpret kernel / TPU hardware / pure-jnp ref) that
+computes the offline Algorithm-5 plan of the whole workload printed
+before the run.
 
 ``--open-loop`` swaps the closed-loop batch drive for production-shaped
 traffic through the asyncio front-end (serve/frontend.py, DESIGN.md
@@ -404,8 +405,8 @@ def build_parser() -> argparse.ArgumentParser:
                     help="also run the old per-request loop")
     ap.add_argument("--sync-backend", default="auto",
                     choices=("auto", "host", "kernel", "ref"),
-                    help="admission-planner backend (auto = pick from "
-                         "the machine abstraction)")
+                    help="backend of the printed offline admission "
+                         "plan (auto = pick from the machine abstraction)")
     ap.add_argument("--admission-sem", default="auto",
                     choices=("auto", "sleeping", "spin", "spin_backoff"),
                     help="live admission-gate semaphore algorithm "

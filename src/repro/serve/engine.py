@@ -15,22 +15,19 @@ single-stream generation). ``SlotServeEngine`` is the production path:
     tokens per dispatch and finished/vacant rows masked (they still
     compute, at fixed shape, but their tokens are frozen and their cache
     writes drop once out of range);
-  * admission driven by the paper's Algorithm-5 semaphore discipline at
-    *both* layers: the host ``AdmissionController`` (a live semaphore
-    from the injected ``SyncLibrary`` — sleeping by default, spin via the
-    library's ``semaphore_kind`` pin) is the occupancy gate on the hot
-    loop, and the library's windowed admission planner — replanned each
-    scheduler round over in-flight holds + queued arrivals through a
-    fixed planning window — decides which queued requests join the next
-    decode iteration (a queued request is admitted iff the timeline
-    grants it with ``waited == 0`` *now*). FIFO grant order is the
+  * admission gated by the paper's Algorithm-5 semaphore: the host
+    ``AdmissionController`` (a live semaphore from the injected
+    ``SyncLibrary`` — sleeping by default, spin via the library's
+    ``semaphore_kind`` pin) grants every slot, and a round admits the
+    FIFO prefix of the queue that the free slots can hold — exactly the
+    queued requests an Algorithm-5 timeline over the in-flight holds
+    grants with ``waited == 0`` *now*. FIFO grant order is the
     semaphore's fairness guarantee, and the engine records it in
     ``grant_log`` so callers can verify it.
 
 All primitive access goes through the injected ``SyncLibrary`` (the
-``sync`` constructor argument): the planner backend (Pallas kernel /
-hardware / pure-jnp ref) and the live gate's algorithm are configuration
-— ``launch/serve.py`` exposes both as CLI flags.
+``sync`` constructor argument): the live gate's algorithm is
+configuration — ``launch/serve.py`` exposes it as ``--admission-sem``.
 
 The engine owns cache layout: models just read/write the arena row they
 are handed (per-slot ``len`` vectors; models/blocks.block_decode).
@@ -77,9 +74,18 @@ _DROP_POS = 2 ** 30
 #: scheduler round: ``serve.*`` inside ``SlotServeEngine.step`` (the
 #: round itself is ``serve.step``, a step annotation numbered by the
 #: engine's round counter), ``frontend.*`` in ``AsyncFrontend``'s loop.
-SPANS = ("serve.step", "serve.admit", "serve.plan", "serve.grant",
-         "serve.grow", "serve.dispatch", "serve.wait", "serve.harvest",
-         "serve.retire", "frontend.pump", "frontend.intake")
+SPANS = ("serve.step", "serve.admit", "serve.grant", "serve.grow",
+         "serve.dispatch", "serve.wait", "serve.harvest", "serve.retire",
+         "frontend.pump", "frontend.intake")
+
+#: Fraction of the page arena lazy-growth admission keeps free for
+#: in-flight top-ups, and the default of ``cache_watermark``.
+_ADMIT_HEADROOM = 0.1
+
+#: Chunks ahead a lazy top-up covers (capped at the request's
+#: admission-time bound), so a long decode pays one grow acquire per
+#: window, not per chunk; one chunk when the pool is under the headroom.
+_PAGE_LOOKAHEAD_CHUNKS = 2
 
 
 class RequestState(str, enum.Enum):
@@ -281,12 +287,13 @@ class SlotServeEngine:
       * ``"lazy"`` (default) — insert grants only the prefill bucket and
         a per-round top-up pass covers each coming chunk, so short-lived
         requests never touch pages they won't fill; admission gates on
-        an ``admit_headroom`` watermark (fraction of the arena kept free
-        for in-flight top-ups) instead of the worst case, and the
-        overflow path — pause the starved row for a round, preempt the
-        youngest grant if *nobody* can decode — is eviction-safe: with
-        greedy decoding both modes emit identical token streams and the
-        engine ``grant_log`` stays the FIFO admission order.
+        a headroom watermark (``_ADMIT_HEADROOM``, the fraction of the
+        arena kept free for in-flight top-ups) instead of the worst
+        case, and the overflow path — pause the starved row for a round,
+        preempt the youngest grant if *nobody* can decode — is
+        eviction-safe: with greedy decoding both modes emit identical
+        token streams and the engine ``grant_log`` stays the FIFO
+        admission order.
 
     ``allocator_wait`` pins the allocator's wait strategy ("spin",
     "spin_backoff", "sleeping") or selects ``"adaptive"`` — re-resolved
@@ -353,16 +360,11 @@ class SlotServeEngine:
     def __init__(self, model, params, *, capacity: int, max_len: int,
                  temperature: float = 0.0, decode_chunk: int = 1,
                  eos_id: Optional[int] = None, seed: int = 0,
-                 pad_prompts_to: Optional[int] = None,
-                 use_admission_kernel: bool = True,
-                 plan_window: int = 64,
                  kv_layout: str = "slots",
                  page_size: int = 16,
                  num_pages: Optional[int] = None,
                  max_pages_per_slot: Optional[int] = None,
                  page_growth: str = "lazy",
-                 admit_headroom: float = 0.1,
-                 page_lookahead_chunks: int = 2,
                  allocator_wait: Optional[str] = None,
                  prefix_sharing: str = "auto",
                  prefix_cache: str = "off",
@@ -403,17 +405,14 @@ class SlotServeEngine:
         self.temperature = temperature
         self.decode_chunk = decode_chunk
         self.eos_id = eos_id
-        self.pad_prompts_to = pad_prompts_to
         self.kv_layout = kv_layout
         self.attention_impl = attention_impl
         # the form the round program's gather reads took, recorded when
         # the decode step is traced (``_chunk_impl``)
         self.kv_grouped = False
         self.sync = sync if sync is not None else SyncLibrary.host_default()
-        # the planning trace holds all K in-flight requests plus the
-        # queued front; a window smaller than capacity would silently
-        # cap effective concurrency at the window
-        self.plan_window = max(plan_window, 2 * capacity)
+        # read by the benchmark's warm-up to size its queue
+        self.plan_window = max(64, 2 * capacity)
         # Right-padded prompt buckets are only sound for attention layers
         # (causal masking hides the pad); Mamba prefill is recurrent, so
         # hybrid/SSM archs prefill at exact prompt length (retrace per
@@ -513,13 +512,7 @@ class SlotServeEngine:
         # when grants come up short (defaults to the admission headroom)
         self.cache_watermark = (float(cache_watermark)
                                 if cache_watermark is not None
-                                else float(admit_headroom))
-        self.admit_headroom = float(admit_headroom)
-        # top-ups cover this many chunks ahead (capped at the request's
-        # admission-time bound) so a long decode pays one grow acquire
-        # per lookahead window, not per chunk; shrinks to one chunk when
-        # the pool is under the headroom watermark
-        self.page_lookahead_chunks = max(int(page_lookahead_chunks), 1)
+                                else _ADMIT_HEADROOM)
 
         if kv_layout == "paged":
             self.pool = PagedSlotPool(
@@ -550,9 +543,6 @@ class SlotServeEngine:
                 if wd is not None:
                     wd(allocator_watchdog_s)
         self.admission = AdmissionController(capacity, lib=self.sync)
-        self._admission_planner = (
-            self.sync.semaphore_planner(capacity, window=self.plan_window)
-            if use_admission_kernel else None)
         self.prefix_index = (PrefixIndex(self.pool.page_size,
                                          self.pool.pages)
                              if self.prefix_sharing else None)
@@ -891,8 +881,8 @@ class SlotServeEngine:
     def _expire_queued(self) -> int:
         """Shed queued requests whose deadline already passed — they
         could not produce a first token in time, so granting them a
-        slot would only burn pages. Runs before admission planning so
-        the Algorithm-5 timeline never plans an expired request."""
+        slot would only burn pages. Runs before admission so an expired
+        request is never granted a slot."""
         if not any(r.deadline_step is not None or r.deadline_s is not None
                    for r in self.queue):
             return 0
@@ -994,46 +984,12 @@ class SlotServeEngine:
             self._round_failures.pop(rid, None)
 
     # ------------------------------------------------------------- admission
-    def _planned_admit_count(self) -> int:
-        """How many FIFO-front queued requests the Algorithm-5 timeline
-        grants *now*, given current in-flight holds. The planner's
-        ``waited == 0`` bit (under-capacity ⇒ immediate entry) is the
-        admission decision."""
-        n_queued = len(self.queue)
-        if n_queued == 0:
-            return 0
-        if self._admission_planner is None:
-            return min(self.pool.n_free, n_queued)
-        with TraceAnnotation("serve.plan"):
-            now = float(self.step_clock)
-            act = sorted(self.active)                      # slot order
-            arr = ([now] * len(act)
-                   + [now + 1e-3 * (i + 1) for i in range(n_queued)])
-            hold = ([float(max(self._steps_left[s], 1)) for s in act]
-                    + [float(r.max_new_tokens) for r in self.queue])
-            n_plan = min(len(arr), self.plan_window)
-            # the planner returns host arrays: this call waits for it
-            _, _, waited = self._admission_planner(
-                np.asarray(arr[:n_plan], np.float32),
-                np.asarray(hold[:n_plan], np.float32))
-        waited_q = waited[len(act):]
-        # FIFO prefix of queued requests granted without waiting
-        n_admit = 0
-        for w in waited_q:
-            if w:
-                break
-            n_admit += 1
-        return n_admit
-
     def _bucket_len(self, n: int) -> int:
         if not self._can_pad:
             return n
-        if self.pad_prompts_to is not None:
-            b = max(self.pad_prompts_to, n)
-        else:
-            b = 8
-            while b < n:
-                b *= 2
+        b = 8
+        while b < n:
+            b *= 2
         # never pad past what a slot can hold — the prompt itself fits by
         # the submit() check, and _pad_cache cannot pad to less than s
         return min(b, self.pool.virtual_max_len)
@@ -1041,7 +997,7 @@ class SlotServeEngine:
     def _headroom_pages(self) -> int:
         """Admission watermark in pages: keep this many pages free for
         in-flight top-ups when admitting under lazy growth."""
-        return int(np.ceil(self.admit_headroom * self.pool.pages.num_pages))
+        return int(np.ceil(_ADMIT_HEADROOM * self.pool.pages.num_pages))
 
     def _watermark_pages(self) -> int:
         """Free-page floor the prefix cache's LRU eviction defends: when
@@ -1125,7 +1081,7 @@ class SlotServeEngine:
         self.rounds_retried += 1
 
     def _admit(self) -> int:
-        """Admit the FIFO front the Algorithm-5 timeline grants now.
+        """Admit the FIFO front of the queue that the free slots hold.
 
         Page grants for the whole admission batch go through ONE
         allocator critical section (``reserve_batch``): staging first
@@ -1140,14 +1096,14 @@ class SlotServeEngine:
         reserve_batch critical section* and only the private remainder
         is granted, so sharing changes what the one acquire does, not
         how many there are. Admission order is untouched: the lookup
-        happens only for the FIFO head the planner already granted — a
+        happens only for the FIFO head the free slots already admit — a
         prefix hit never lets a younger request jump a page-starved
         older one. Requests admitted in the same batch cannot adopt
         from each other (the donor's pages exist only after its
         insert); the index warms for the next round.
         """
         had_decoders = bool(self.active)
-        n_admit = self._planned_admit_count()
+        n_admit = min(self.pool.n_free, len(self.queue))
         if self.prefill_chunk:
             with TraceAnnotation("serve.grant"):
                 return self._admit_chunked(n_admit)
@@ -1176,7 +1132,7 @@ class SlotServeEngine:
                 need = max(lp + req.max_new_tokens - 1, lp)
                 grant = (max(bucket,
                              min(bucket + self.decode_chunk
-                                 * self.page_lookahead_chunks, need))
+                                 * _PAGE_LOOKAHEAD_CHUNKS, need))
                          if lazy else reserve)
                 sh_len, sh_ids, from_cache = self._lookup_prefix(
                     req.prompt, bucket, 0)
@@ -1211,9 +1167,8 @@ class SlotServeEngine:
                         if not fits(credit()):
                             break
                 self.queue.popleft()
-                # Algorithm-5 wait(): never blocks here because the kernel
-                # only granted as many requests as there are free slots —
-                # the planner and the gate agree by construction.
+                # Algorithm-5 wait(): never blocks here because the round
+                # admits no more requests than there are free slots.
                 if not self.admission.acquire_slot(timeout=5.0):
                     self.queue.appendleft(req)
                     break
@@ -1357,10 +1312,10 @@ class SlotServeEngine:
             n_shared = 0 if sh_ids is None else int(sh_ids.size)
             if self.kv_layout == "paged":
                 first = min(sh_len + C, need)
-                window = min(sh_len + C * self.page_lookahead_chunks, need)
+                window = min(sh_len + C * _PAGE_LOOKAHEAD_CHUNKS, need)
                 generous = min(max(lp, first)
                                + self.decode_chunk
-                               * self.page_lookahead_chunks, need)
+                               * _PAGE_LOOKAHEAD_CHUNKS, need)
 
                 def pick(extra: int) -> Optional[int]:
                     if lazy:
@@ -1679,7 +1634,7 @@ class SlotServeEngine:
             # just-this-chunk when the pool is under the watermark so a
             # speculative grant never starves a must-have one
             tight = self.pool.pages.n_free <= self._headroom_pages()
-            look = 1 if tight else self.page_lookahead_chunks
+            look = 1 if tight else _PAGE_LOOKAHEAD_CHUNKS
             items = []
             if lazy:
                 for s in order:
@@ -1764,9 +1719,9 @@ class SlotServeEngine:
     def step(self) -> int:
         """One scheduler round: apply round-boundary cancellations and
         queue-deadline expiries, re-tune the allocator's wait strategy
-        from measured contention, admit per the kernel plan (one
-        batched page grant + prefix-adoption increfs), lazily top up
-        active slots and apply any CoW splits (one batched
+        from measured contention, admit the FIFO prefix the free slots
+        hold (one batched page grant + prefix-adoption increfs), lazily
+        top up active slots and apply any CoW splits (one batched
         grant/decref), then one fixed-shape decode dispatch of
         ``decode_chunk`` tokens, then retire finished rows (one batched
         decref/free — cancellation-deferred pages ride this same

@@ -16,9 +16,10 @@ Every primitive is reached through an injected ``repro.sync.SyncLibrary``
 — no direct imports of hostsync or the kernel ops — so the live gate's
 algorithm (sleeping vs spin, the spin-vs-sleep admission knob) and the
 planner's backend (Pallas kernel / hardware / pure-jnp ref) are
-configuration, not code. ``AdmissionController`` is the host-side gate;
-``plan_admission`` is the planner used for batching decisions and
-reported in benchmarks/serving.
+configuration, not code. ``AdmissionController`` is the host-side gate
+that grants every slot; ``plan_admission`` predicts a workload's
+timeline offline, as reported by benchmarks/servebench.py and
+launch/serve.py.
 """
 
 from __future__ import annotations

@@ -13,8 +13,7 @@
 #                  retrace avoidance for all three kernel families
 #
 # serve/, launch/, and benchmarks/ consume primitives exclusively through
-# an injected SyncLibrary; core/api.py is a deprecation shim onto this
-# package. See DESIGN.md §8.
+# an injected SyncLibrary. See DESIGN.md §8.
 
 from repro.sync.backends import (  # noqa: F401
     HostBackend,

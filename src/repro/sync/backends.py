@@ -32,9 +32,9 @@ import numpy as np
 from repro.core import hostsync
 from repro.core.abstraction import WaitStrategy
 
-# Host algorithm tables (moved here from core/api.py). The host can truly
-# block, so "auto" on a host machine may pick the futex, which the paper
-# identifies as CPU-only (no blocking on the GPU).
+# Host algorithm tables. The host can truly block, so "auto" on a host
+# machine may pick the futex, which the paper identifies as CPU-only (no
+# blocking on the GPU).
 HOST_MUTEXES = {
     "spin": lambda strat: hostsync.SpinMutex(strategy=WaitStrategy.SPIN),
     "spin_backoff": lambda strat: hostsync.SpinMutex(

@@ -17,15 +17,13 @@ default, and every axis can be pinned:
 
 Live objects always run on the host control plane (threading); plans run
 on the selected backend (Pallas interpret / hardware / pure-jnp ref /
-observed host execution). ``semaphore_planner`` hands schedulers a
-windowed hot-loop planner (see ``window.WindowedPlanner``) on a
-fast-planning backend.
+observed host execution).
 """
 
 from __future__ import annotations
 
 import dataclasses
-from typing import Callable, Optional, Tuple
+from typing import Optional
 
 import numpy as np
 
@@ -338,24 +336,3 @@ class SyncLibrary:
                            release=np.asarray(rel), done=int(done),
                            stragglers=np.asarray(strag), required=required,
                            backend=bk.name)
-
-    # ------------------------------------------------------------- hot loops
-    def semaphore_planner(
-        self, capacity: int, *, window: int = 32,
-        backend: Optional[str] = None,
-    ) -> Callable[[np.ndarray, np.ndarray],
-                  Tuple[np.ndarray, np.ndarray, np.ndarray]]:
-        """A raw ``(arrivals, holds) -> (grant, release, waited)`` planner
-        for scheduler hot loops: fixed windowed shapes (one compiled
-        kernel per power-of-2 bucket), numpy in/out, no dataclass
-        overhead. Arrivals must be sorted ascending."""
-        bk = self._backend(backend or self.planning_backend_name())
-
-        def plan(arrivals, holds):
-            g, r, w, _ = bk.plan_semaphore(
-                np.asarray(arrivals, np.float32),
-                np.asarray(holds, np.float32),
-                capacity, window=window)
-            return g, r, w
-
-        return plan

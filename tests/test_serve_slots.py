@@ -80,7 +80,14 @@ def test_slot_engine_n_gt_k_roundtrip(lm_setup):
     rng = np.random.default_rng(0)
     for _ in range(7):
         eng.submit(rng.integers(0, cfg.vocab_size, 10), max_new_tokens=5)
-    eng.run_until_done(max_rounds=100)
+    for _ in range(100):
+        if not (eng.queue or eng.active):
+            break
+        # each round admits the FIFO prefix the free slots hold
+        want = min(eng.capacity - len(eng.active), len(eng.queue))
+        granted = len(eng.grant_log)
+        eng.step()
+        assert len(eng.grant_log) - granted == want
     assert len(eng.finished) == 7
     assert eng.grant_log == sorted(eng.grant_log)          # FIFO grants
     assert all(len(r.out_tokens) == 5 for r in eng.finished)
@@ -232,33 +239,43 @@ def _host_semaphore_trace(n, capacity, completion_rng):
 
 
 @settings(max_examples=5, deadline=None)
-@given(n=st.integers(6, 16), cap=st.integers(1, 4),
+@given(n=st.integers(6, 16), cap=st.integers(1, 4), held=st.integers(0, 4),
        seed=st.integers(0, 10_000))
-def test_admission_equivalence_host_vs_kernel(n, cap, seed):
+def test_admission_equivalence_host_vs_kernel(n, cap, held, seed):
     """Property: the real Algorithm-5 host semaphore and the Pallas
     admission kernel agree on a FIFO arrival trace — same waited set,
     FIFO handoff order among waiters, occupancy <= K — even when holds
-    complete out of order."""
+    complete out of order. With ``held`` requests in flight at t=0, the
+    queue behind them enters without waiting exactly for its first
+    ``min(cap - held, n)`` requests: the engine's admission rule
+    ``min(n_free, n_queued)``."""
+    held = min(held, cap)
+    total = held + n
     rng = np.random.default_rng(seed)
-    holds = rng.integers(1, 4, n).astype(np.float32)
-    # kernel timeline: arrivals strictly increasing, gaps tiny vs holds
-    arrivals = np.arange(n, dtype=np.float32) * 1e-3
+    holds = rng.integers(1, 4, total).astype(np.float32)
+    # kernel timeline: the holders at t=0, then the queue strictly
+    # increasing behind them, gaps tiny vs holds (every hold >= 1)
+    arrivals = np.concatenate([
+        np.zeros(held, np.float32),
+        np.arange(1, n + 1, dtype=np.float32) * 1e-3])
     g, r, waited = semaphore_admission_window(
         arrivals, holds, capacity=cap, window=32)
     # under-capacity prefix enters immediately; the rest queue
-    assert list(waited) == [0] * min(cap, n) + [1] * max(n - cap, 0)
+    assert list(waited) == [0] * min(cap, total) + [1] * max(total - cap, 0)
+    k_q = min(cap - held, n)
+    assert list(waited[held:]) == [0] * k_q + [1] * (n - k_q)
     assert np.all(np.diff(g) >= -1e-5)          # FIFO: grants monotone
-    for i in range(n):                          # occupancy bound
+    for i in range(total):                      # occupancy bound
         assert np.sum((g <= g[i] + 1e-6) & (r > g[i] + 1e-6)) <= cap
 
-    order, max_occ = _host_semaphore_trace(n, cap, rng)
+    order, max_occ = _host_semaphore_trace(total, cap, rng)
     assert max_occ <= cap
     # the non-waited set is the first `cap` arrivals (granted in any
     # interleaving); every ticketed waiter is handed off FIFO — exactly
     # the kernel's deterministic grant order
-    k = min(cap, n)
+    k = min(cap, total)
     assert sorted(order[:k]) == list(range(k))
-    assert order[k:] == list(range(k, n))
+    assert order[k:] == list(range(k, total))
 
 
 def test_admission_window_matches_unpadded():

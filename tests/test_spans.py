@@ -108,8 +108,7 @@ def test_every_span_fires_and_nests(traced):
             assert any(line == o[0] and o[2] <= s and t <= o[3]
                        for o in spans), (inner, outer, s)
 
-    for name in ("serve.plan", "serve.grant"):
-        inside(name, "serve.admit")
+    inside("serve.grant", "serve.admit")
     for name in ("serve.admit", "serve.grow", "serve.dispatch",
                  "serve.wait", "serve.harvest", "serve.retire"):
         inside(name, "serve.step")

@@ -65,8 +65,9 @@ def _compile(sharding, fn, *shapes, **static):
 
 
 def test_semaphore_planner_at_plan_window(one_chip):
-    """The admission planner the engine runs every round, at its
-    default plan window (64) and the smoke run's capacity."""
+    """The sync library's Algorithm-5 planner behind
+    ``scheduler.plan_admission``, at a 64-arrival window and the smoke
+    run's capacity."""
     window = 64
     c = _compile(one_chip, semaphore_admission,
                  ((window,), jnp.float32), ((window,), jnp.float32),
